@@ -26,6 +26,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 
 def _time(fn, *args, iters=10, warmup=2):
     for _ in range(warmup):
@@ -124,7 +126,8 @@ def bench_flash_attention():
     v = jnp.asarray(rng.normal(size=(1, 4, 256, 64)), jnp.float32)
     t_ref = _time(jax.jit(lambda q, k, v: attention_ref(q, k, v)), q, k, v,
                   iters=3, warmup=1)
-    got = flash_attention_op(q, k, v)
+    impl = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    got = flash_attention_op(q, k, v, impl=impl)
     want = attention_ref(q, k, v)
     err = float(jnp.abs(got - want).max())
     print(f"kernel_flash_attention,{t_ref:.0f},interpret_max_err={err:.2e}")
@@ -721,6 +724,7 @@ def main() -> None:
                     help="write serving + kernel numbers to PATH "
                          "(default BENCH_serving.json)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     kernels = {

@@ -18,6 +18,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 sys.path.insert(0, str(Path(__file__).parent.parent))
 
 from benchmarks.repro_tables import fp32_row, run_variant  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
                     help="override the tier's CGMQ epoch count (e.g. stop a "
                          "run early, then --resume with the full count)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.resume and not args.ckpt:
         ap.error("--resume requires --ckpt")
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import bop as bop_lib
 from repro.core.controller import CGMQConfig, export_bits
 from repro.core.pipeline import PipelineConfig, run_pipeline
@@ -79,6 +80,7 @@ def main():
     ap.add_argument("--seed", type=int, default=None,
                     help="per-request sampling seed")
     args = ap.parse_args()
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     # 4-class toy problem with a planted linear rule + noise
     w_true = rng.normal(size=(D_IN, D_OUT))

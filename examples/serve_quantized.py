@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.models import transformer as tfm
 from repro.serving import (SamplingParams, ServingEngine, WindowSpec,
@@ -86,6 +87,7 @@ def main():
                          "forever (attention sinks) — always attended, "
                          "never evicted")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.sink_blocks and args.window is None:
         ap.error("--sink-blocks requires --window")
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import bop as bop_lib
@@ -56,6 +57,7 @@ def main():
                     help="keep existing checkpoints (default: start fresh)")
     ap.add_argument("--inject-failure-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if not args.resume:
         import shutil

@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import IMPLS
+
 from . import gates as G
 from .quantizer import fake_quant
 
@@ -130,7 +132,7 @@ class QuantContext:
     ):
         assert mode in ("off", "collect", "calibrate", "train", "export",
                         "serve")
-        assert matmul_impl in ("ref", "pallas", "pallas_interpret")
+        assert matmul_impl in IMPLS, matmul_impl
         self.mode = mode
         self.cfg = cfg or QuantConfig()
         self.gates = gates or {}

@@ -56,12 +56,12 @@ def fake_quant_pallas(
     *,
     block_m: int = 256,
     block_n: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x: (M, N) fp32; gate/beta: (N,). Returns fake-quantized x.
 
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on TPU pass ``interpret=False``.
+    ``interpret`` runs the kernel body under the Pallas interpreter (CPU
+    tests only); on TPU it is always False.
     """
     m, n = x.shape
     bm, bn = min(block_m, m), min(block_n, n)

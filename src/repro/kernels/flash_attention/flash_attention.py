@@ -90,7 +90,7 @@ def flash_attention_pallas(
     causal: bool = True, window: int | None = None,
     softcap: float | None = None,
     block_q: int = 128, block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """q/k/v: (B, H, S, D) -> (B, H, S, D). S % block sizes handled by cdiv."""
     b, h, s, d = q.shape

@@ -7,19 +7,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_flags
+
 from .paged_attention import paged_attention_pallas
 from .ref import paged_attention_ref
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("window", "sinks", "softcap", "use_pallas", "interpret"))
+    static_argnames=("window", "sinks", "softcap", "impl"))
 def paged_attention_op(q, k_pool, v_pool, block_table, pos, *,
                        window: int | None = None,
                        sinks: int = 0,
                        softcap: float | None = None,
-                       use_pallas: bool = False,
-                       interpret: bool = True,
+                       impl: str,
                        k_scale=None, v_scale=None):
     """q: (B, KV, G, hd); pools: (num_blocks, bs, KV, hd) float — or integer
     codes with ``k_scale``/``v_scale`` (num_blocks, bs, KV, num_groups) fp16
@@ -31,6 +32,7 @@ def paged_attention_op(q, k_pool, v_pool, block_table, pos, *,
     specializations without a static flag.
     """
     pos = jnp.asarray(pos, jnp.int32)
+    use_pallas, interpret = pallas_flags(impl)
     if use_pallas:
         return paged_attention_pallas(
             q, k_pool, v_pool, block_table, pos,

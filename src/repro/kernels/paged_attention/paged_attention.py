@@ -38,11 +38,14 @@ Quantized pools (DESIGN.md §14) add a **fused dequant-on-block-load**: the
 per-group fp16 scales ride in as two extra block-mapped operands whose
 BlockSpec index_map reads the SAME ``table[b, j]`` entry as the code
 blocks, so scale DMA is paged exactly like the codes; the affine is applied
-in-register (int4 nibbles unpacked first) before the scores dot, and the
-online-softmax carry is untouched.
+in-register before the scores dot, and the online-softmax carry is
+untouched. The group scales are broadcast to lanes by select, never by a
+lane-splitting reshape, and int4 blocks are consumed as two nibble planes
+(even and odd head elements) whose partial dots sum to the full score, so
+the kernel never interleaves lanes.
 
-On CPU containers the kernel runs in interpret mode (the repo-wide kernel
-contract, DESIGN.md §3); on TPU it lowers natively.
+The kernel runs natively on TPU (``interpret=False``) and in interpret
+mode only in CPU tests (DESIGN.md §3).
 """
 
 from __future__ import annotations
@@ -54,16 +57,65 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.quant.kv import dequant_codes, unpack_int4
-
 NEG_INF = -1e30
+
+
+def _f16_bits_to_f32(h):
+    """fp16 bit patterns (int16) -> fp32 values, exact for normals, zero,
+    inf and NaN (subnormals flush to zero; KV scales are floored at an
+    fp16-normal value, so none occur). Mosaic has no f16 vector loads on
+    v5e, so the scale pools enter the kernel bitcast to int16."""
+    h = h.astype(jnp.int32) & 0xFFFF
+    sign = (h & 0x8000) << 16
+    exp = (h >> 10) & 0x1F
+    mant = (h & 0x3FF) << 13
+    bits = jnp.where(exp == 0x1F, 0x7F800000 | mant, ((exp + 112) << 23) | mant)
+    bits = jnp.where(exp == 0, 0, bits)
+    return jax.lax.bitcast_convert_type(sign | bits, jnp.float32)
+
+
+def _expand_scale(scale, width: int, group_width: int):
+    """(bs, KV, ng) int16-coded fp16 group scales -> (bs, KV, width)
+    per-lane fp32 scales.
+
+    Lane ``d`` takes group ``d // group_width``: a select per group over a
+    lane broadcast of that group's 1-wide slice. The obvious
+    ``(..., hd) -> (..., ng, group)`` reshape splits the lane axis, which
+    Mosaic refuses (``infer-vector-layout: unsupported shape cast``).
+    """
+    s = _f16_bits_to_f32(scale)
+    shape = s.shape[:-1] + (width,)
+    group = jax.lax.broadcasted_iota(jnp.int32, shape, 2) // group_width
+    out = jnp.broadcast_to(s[..., 0:1], shape)
+    for gi in range(1, s.shape[-1]):
+        out = jnp.where(group == gi, jnp.broadcast_to(s[..., gi:gi + 1], shape),
+                        out)
+    return out
+
+
+def _dequant_planes(codes, scale, planes: int, group_size: int):
+    """Fused dequant of one block's codes into ``planes`` fp32 lane planes.
+
+    int8 (``planes == 1``): the codes times the lane-expanded scales.
+    int4 (``planes == 2``): byte ``i`` holds head elements ``2i`` (low
+    nibble) and ``2i+1`` (high nibble), both in group ``i // (gs/2)``; the
+    two nibble planes are the even and odd head elements, each
+    ``hd/2`` lanes wide, so no lane interleave is ever formed in-kernel.
+    """
+    width = codes.shape[-1]
+    s = _expand_scale(scale, width, group_size // planes)
+    if planes == 1:
+        return [codes.astype(jnp.float32) * s]
+    p = codes.astype(jnp.int32)
+    return [((p & 0xF) - 8).astype(jnp.float32) * s,
+            (((p >> 4) & 0xF) - 8).astype(jnp.float32) * s]
 
 
 def _kernel(table_ref, pos_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
             block_size: int, blocks: int,
             kv_heads: int, groups: int, window: int | None,
             sinks: int, softcap: float | None, scale: float,
-            head_dim: int, group_size: int = 0, bits: int = 8):
+            planes: int, group_size: int = 0):
     if group_size:  # quantized: two scale operands precede the output
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -91,24 +143,22 @@ def _kernel(table_ref, pos_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)          # (KV*G, hd)
         if group_size:
-            kc, vc = k_ref[0], v_ref[0]           # (bs, KV, packed)
-            if bits == 4:
-                kc = unpack_int4(kc, head_dim)
-                vc = unpack_int4(vc, head_dim)
-            # fused dequant in-register: (bs, KV, ng, G) * scale
-            k = dequant_codes(kc, ks_ref[0], head_dim, group_size)
-            v = dequant_codes(vc, vs_ref[0], head_dim, group_size)
+            # fused dequant in-register, one fp32 plane per lane group
+            ks = _dequant_planes(k_ref[0], ks_ref[0], planes, group_size)
+            vs = _dequant_planes(v_ref[0], vs_ref[0], planes, group_size)
         else:
-            k = k_ref[0].astype(jnp.float32)      # (bs, KV, hd)
-            v = v_ref[0].astype(jnp.float32)      # (bs, KV, hd)
-        qr = q.reshape(kv_heads, groups, q.shape[-1])
-        # batched over the KV head axis: (KV, G, hd) x (bs, KV, hd)
-        s = jax.lax.dot_general(
-            qr, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * scale                                  # (KV, G, bs)
+            ks = [k_ref[0].astype(jnp.float32)]   # (bs, KV, hd)
+            vs = [v_ref[0].astype(jnp.float32)]
+        s = None
+        for pi in range(planes):
+            qr = q_ref[0, pi].astype(jnp.float32).reshape(kv_heads, groups, -1)
+            # batched over the KV head axis: (KV, G, w) x (bs, KV, w)
+            sp = jax.lax.dot_general(
+                qr, ks[pi], (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32)  # (KV, G, bs)
+            s = sp if s is None else s + sp
+        s = s * scale
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
         cols = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -126,25 +176,26 @@ def _kernel(table_ref, pos_ref, fl_ref, q_ref, k_ref, v_ref, *rest,
         pexp = jnp.exp(s - m_new)                  # (KV*G, bs)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = alpha * l_prev + jnp.sum(pexp, axis=1)[:, None]
-        pv = jax.lax.dot_general(
-            pexp.reshape(kv_heads, groups, -1), v,
-            (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )                                          # (KV, G, hd)
-        acc_scr[...] = acc_scr[...] * alpha + pv.reshape(acc_scr.shape)
+        pr = pexp.reshape(kv_heads, groups, -1)
+        for pi in range(planes):
+            pv = jax.lax.dot_general(
+                pr, vs[pi], (((2,), (0,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32)  # (KV, G, w)
+            acc_scr[pi] = acc_scr[pi] * alpha + pv.reshape(acc_scr.shape[1:])
         m_scr[...] = m_new
 
     @pl.when(j == blocks - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        for pi in range(planes):
+            o_ref[0, pi] = (acc_scr[pi] / denom).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, block_table, pos, *,
                            window: int | None = None,
                            sinks: int = 0,
                            softcap: float | None = None,
-                           interpret: bool = True,
+                           interpret: bool,
                            k_scale=None, v_scale=None):
     """q: (B, KV, G, hd); pools: (num_blocks, bs, KV, hd) float or
     (num_blocks, bs, KV, packed_head) codes + ``k_scale``/``v_scale``
@@ -153,20 +204,25 @@ def paged_attention_pallas(q, k_pool, v_pool, block_table, pos, *,
 
     ``window``/``sinks`` (both static) enable the §17 block-sparse walk:
     the per-slot first-live-block index is derived from ``pos`` here and
-    scalar-prefetched so dead blocks are never loaded (module docstring)."""
+    scalar-prefetched so dead blocks are never loaded (module docstring).
+
+    int4 pools run as two lane planes (even / odd head elements): q is
+    de-interleaved into the planes here and the output re-interleaved
+    after the call, outside the kernel."""
     b, kvh, g, hd = q.shape
     bs = k_pool.shape[1]
     mb = block_table.shape[1]
     hdp = k_pool.shape[-1]
     quant = k_scale is not None
+    ng = k_scale.shape[-1] if quant else 0
+    group_size = hd // ng if quant else 0
+    # int4 codes (packed uint8) split into even/odd lane planes
+    planes = 2 if quant and k_pool.dtype == jnp.uint8 else 1
     if quant:
-        ng = k_scale.shape[-1]
-        group_size = hd // ng
-        bits = 8 if k_pool.dtype == jnp.int8 else 4
-        assert ng * group_size == hd, (hd, ng)
-    else:
-        ng, group_size, bits = 0, 0, 8
-    qf = q.reshape(b, kvh * g, hd)
+        assert ng * group_size == hd and group_size % planes == 0, (hd, ng)
+    w = hd // planes
+    # (B, P, KV*G, w): plane p holds head elements d with d % P == p
+    qp = q.reshape(b, kvh * g, w, planes).transpose(0, 3, 1, 2)
     sink_blocks = -(-sinks // bs)
     if window is not None:
         # first block the sliding window still reaches; sink blocks pinned
@@ -180,37 +236,38 @@ def paged_attention_pallas(q, k_pool, v_pool, block_table, pos, *,
         return (jnp.where(live, jnp.maximum(tbl[bi, j], 0), 0), 0, 0, 0)
 
     def row_map(bi, j, tbl, ps, fl):
-        return (bi, 0, 0)
+        return (bi, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, kvh * g, hd), row_map),
+        pl.BlockSpec((1, planes, kvh * g, w), row_map),
         pl.BlockSpec((1, bs, kvh, hdp), table_map),
         pl.BlockSpec((1, bs, kvh, hdp), table_map),
     ]
-    operands = [qf, k_pool, v_pool]
+    operands = [qp, k_pool, v_pool]
     if quant:
         # scale blocks page through the SAME table entry as the codes
         in_specs += [pl.BlockSpec((1, bs, kvh, ng), table_map)] * 2
-        operands += [k_scale, v_scale]
+        operands += [jax.lax.bitcast_convert_type(a, jnp.int16)
+                     for a in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh * g, hd), row_map),
+        out_specs=pl.BlockSpec((1, planes, kvh * g, w), row_map),
         scratch_shapes=[
             pltpu.VMEM((kvh * g, 1), jnp.float32),   # running max m
             pltpu.VMEM((kvh * g, 1), jnp.float32),   # running denom l
-            pltpu.VMEM((kvh * g, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((planes, kvh * g, w), jnp.float32),  # output acc
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _kernel, block_size=bs, blocks=mb, kv_heads=kvh, groups=g,
             window=window, sinks=sinks, softcap=softcap, scale=hd ** -0.5,
-            head_dim=hd, group_size=group_size, bits=bits,
+            planes=planes, group_size=group_size,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh * g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, planes, kvh * g, w), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
     )(block_table, pos, fl, *operands)
-    return out.reshape(b, kvh, g, hd)
+    return out.transpose(0, 2, 3, 1).reshape(b, kvh, g, hd)

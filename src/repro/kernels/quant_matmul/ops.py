@@ -4,7 +4,8 @@ The kernels compute the complete affine dequant
 ``y = scale * (x @ codes) + bias * rowsum(x)`` (== ``x @ (codes*scale+bias)``
 exactly) in their epilogue; these wrappers flatten the leading activation
 dims, compute ``rowsum(x)`` (one VPU reduction, fused into the x load by
-XLA) and pick the Pallas kernel or the pure-jnp oracle. See quant_matmul.py
+XLA) and pick the Pallas kernel or the pure-jnp oracle by ``impl``
+(``repro.kernels.pallas_flags``). See quant_matmul.py
 for the kernel contracts.
 
 ``quant_matmul_op`` is the raw int8 entry point (unchanged: the oracle path
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantizer import quantize_to_int
+from repro.kernels import pallas_flags
 
 from .quant_matmul import (int_matmul_packed_pallas, int_matmul_pallas,
                            quant_matmul_packed_pallas, quant_matmul_pallas)
@@ -37,19 +39,19 @@ from .ref import (int_matmul_packed_ref, int_matmul_ref,
                   quant_matmul_packed_ref, quant_matmul_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("impl",))
 def quant_matmul_op(
     x: jnp.ndarray,
     codes: jnp.ndarray,
     scale: jnp.ndarray,
     bias: jnp.ndarray,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    impl: str,
 ) -> jnp.ndarray:
     """y = x @ (codes*scale + bias); x: (..., K), codes: (K, N) int8."""
     orig = x.shape
     x2 = x.reshape(-1, orig[-1]).astype(jnp.float32)
+    use_pallas, interpret = pallas_flags(impl)
     if use_pallas:
         rowsum = jnp.sum(x2, axis=1)
         y = quant_matmul_pallas(x2, codes, scale, bias, rowsum,
@@ -60,7 +62,7 @@ def quant_matmul_op(
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bits", "k", "use_pallas", "interpret"))
+                   static_argnames=("bits", "k", "impl"))
 def quant_matmul_packed_op(
     x: jnp.ndarray,
     packed: jnp.ndarray,
@@ -69,12 +71,12 @@ def quant_matmul_packed_op(
     *,
     bits: int,
     k: int,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    impl: str,
 ) -> jnp.ndarray:
     """Packed twin of ``quant_matmul_op``: packed (ceil(K/per), N) uint8."""
     orig = x.shape
     x2 = x.reshape(-1, orig[-1]).astype(jnp.float32)
+    use_pallas, interpret = pallas_flags(impl)
     if use_pallas:
         rowsum = jnp.sum(x2, axis=1)
         y = quant_matmul_packed_pallas(x2, packed, scale, bias, rowsum,
@@ -85,8 +87,7 @@ def quant_matmul_packed_op(
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("act_bits", "act_signed", "use_pallas",
-                                    "interpret"))
+                   static_argnames=("act_bits", "act_signed", "impl"))
 def int_matmul_op(
     x: jnp.ndarray,
     codes: jnp.ndarray,
@@ -97,8 +98,7 @@ def int_matmul_op(
     *,
     act_bits: int,
     act_signed: bool = True,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    impl: str,
 ) -> jnp.ndarray:
     """Integer entry point: quantize ``x`` per-tensor, int8×int8 GEMM.
 
@@ -115,6 +115,7 @@ def int_matmul_op(
     eff_scale = sx * scale
     eff_bias = sx * bias
     const = bx * (scale * colsum.astype(jnp.float32) + k * bias)
+    use_pallas, interpret = pallas_flags(impl)
     if use_pallas:
         y = int_matmul_pallas(qx, codes, eff_scale, eff_bias, rowsum, const,
                               interpret=interpret)
@@ -125,7 +126,7 @@ def int_matmul_op(
 
 @functools.partial(jax.jit,
                    static_argnames=("bits", "k", "act_bits", "act_signed",
-                                    "use_pallas", "interpret"))
+                                    "impl"))
 def int_matmul_packed_op(
     x: jnp.ndarray,
     packed: jnp.ndarray,
@@ -138,8 +139,7 @@ def int_matmul_packed_op(
     k: int,
     act_bits: int,
     act_signed: bool = True,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    impl: str,
 ) -> jnp.ndarray:
     """Packed twin of ``int_matmul_op``: sub-byte weight codes decoded to
     int8 in-kernel, same on-the-fly activation quantization."""
@@ -150,6 +150,7 @@ def int_matmul_packed_op(
     eff_scale = sx * scale
     eff_bias = sx * bias
     const = bx * (scale * colsum.astype(jnp.float32) + k * bias)
+    use_pallas, interpret = pallas_flags(impl)
     if use_pallas:
         y = int_matmul_packed_pallas(qx, packed, eff_scale, eff_bias, rowsum,
                                      const, bits=bits, k=k,
@@ -160,8 +161,7 @@ def int_matmul_packed_op(
     return y.reshape(orig[:-1] + (packed.shape[-1],))
 
 
-def quant_matmul_qt(x, qt, *, act_spec=None, use_pallas: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+def quant_matmul_qt(x, qt, *, impl: str, act_spec=None) -> jnp.ndarray:
     """Serving dispatcher: ``y = x @ dequant(qt)`` off a QuantizedTensor.
 
     Static dispatch on ``qt.storage_bits`` (pytree aux data, so each jit /
@@ -183,16 +183,12 @@ def quant_matmul_qt(x, qt, *, act_spec=None, use_pallas: bool = True,
         if qt.storage_bits == 8:
             return int_matmul_op(
                 x, qt.codes, scale, bias, colsum, act_beta,
-                act_bits=act_spec.bits, act_signed=act_spec.signed,
-                use_pallas=use_pallas, interpret=interpret)
+                act_bits=act_spec.bits, act_signed=act_spec.signed, impl=impl)
         return int_matmul_packed_op(
             x, qt.codes, scale, bias, colsum, act_beta,
             bits=qt.storage_bits, k=qt.k, act_bits=act_spec.bits,
-            act_signed=act_spec.signed, use_pallas=use_pallas,
-            interpret=interpret)
+            act_signed=act_spec.signed, impl=impl)
     if qt.storage_bits == 8:
-        return quant_matmul_op(x, qt.codes, scale, bias,
-                               use_pallas=use_pallas, interpret=interpret)
+        return quant_matmul_op(x, qt.codes, scale, bias, impl=impl)
     return quant_matmul_packed_op(
-        x, qt.codes, scale, bias, bits=qt.storage_bits, k=qt.k,
-        use_pallas=use_pallas, interpret=interpret)
+        x, qt.codes, scale, bias, bits=qt.storage_bits, k=qt.k, impl=impl)

@@ -39,6 +39,8 @@ Kernel contract (DESIGN.md §8/§11):
     bias:   (N,)    fp32 per-output-channel offset (asymmetric / unsigned
                     grids; exactly zero only for symmetric signed grids)
     rowsum: (M,)    fp32 ``sum_k x[m, k]``
+    (the wrappers hand the vectors to Mosaic as (1, N) rows and an (M, 1)
+    column, so every operand block is 2-D and lane-dense)
     out:    (M, N)  fp32 ``x @ (codes * scale + bias)``, exact in fp32
 
 The INTEGER variants (`int_matmul_pallas` / `int_matmul_packed_pallas`,
@@ -70,6 +72,30 @@ from jax.experimental.pallas import tpu as pltpu
 from .layout import gemm_blocks, packed_blocks, unpack_tile
 
 
+# Per-channel vectors ride as lane-dense 2-D blocks: (1, N) rows for the
+# per-output-channel terms and an (M, 1) column for rowsum. Mosaic refuses
+# 1-D (bn,) blocks of an f32[N] operand whose XLA tiling is T(1024).
+def _row_spec(bn: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, bn), lambda i, j, kk: (0, j))
+
+
+def _col_spec(bm: int) -> pl.BlockSpec:
+    return pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0))
+
+
+def _k_rows(shape, bk: int) -> jnp.ndarray:
+    """Global K index of each row of a (bk, bn) weight tile at this step."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) + pl.program_id(2) * bk
+
+
+def _row(v: jnp.ndarray) -> jnp.ndarray:
+    return v.reshape(1, -1)
+
+
+def _col(v: jnp.ndarray) -> jnp.ndarray:
+    return v.reshape(-1, 1)
+
+
 def _kernel(x_ref, c_ref, s_ref, b_ref, r_ref, o_ref, *, k_steps: int,
             k_total: int, bk: int):
     @pl.when(pl.program_id(2) == 0)
@@ -95,8 +121,7 @@ def _kernel(x_ref, c_ref, s_ref, b_ref, r_ref, o_ref, *, k_steps: int,
         # Affine dequant on the resident output tile:
         #   y = scale * (x @ codes) + bias * rowsum(x)
         o_ref[...] = (
-            o_ref[...] * s_ref[...][None, :]
-            + r_ref[...][:, None] * b_ref[...][None, :]
+            o_ref[...] * s_ref[...] + r_ref[...] * b_ref[...]
         )
 
 
@@ -110,7 +135,7 @@ def quant_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x: (M, K); codes: (K, N) int8; scale/bias: (N,); rowsum: (M,).
 
@@ -130,13 +155,13 @@ def quant_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
+            _row_spec(bn),
+            _row_spec(bn),
+            _col_spec(bm),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         interpret=interpret,
-    )(x, codes, scale, bias, rowsum)
+    )(x, codes, _row(scale), _row(bias), _col(rowsum))
 
 
 # ---------------------------------------------------------------------------
@@ -151,11 +176,13 @@ def _packed_kernel(x_ref, p_ref, s_ref, b_ref, r_ref, o_ref, *, bits: int,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)                # (bm, bk)
-    # Mask x columns past K: pack-padding words and ragged-K block tails
-    # then multiply a zeroed activation, so garbage codes contribute nothing.
-    k0 = pl.program_id(2) * bk
-    kx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + k0
-    x = jnp.where(kx < k_total, x, 0.0)
+    if k_steps * bk != k_total:
+        # Mask x columns past K: pack-padding words and ragged-K block
+        # tails then multiply a zeroed activation, so garbage codes (and a
+        # non-finite out-of-bounds activation) contribute nothing.
+        k0 = pl.program_id(2) * bk
+        kx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + k0
+        x = jnp.where(kx < k_total, x, 0.0)
     p = p_ref[...].astype(jnp.int32)                  # (bk // per, bn)
     codes = unpack_tile(p, bits).astype(jnp.float32)  # (bk, bn)
     o_ref[...] += jax.lax.dot(x, codes, preferred_element_type=jnp.float32)
@@ -163,8 +190,7 @@ def _packed_kernel(x_ref, p_ref, s_ref, b_ref, r_ref, o_ref, *, bits: int,
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
         o_ref[...] = (
-            o_ref[...] * s_ref[...][None, :]
-            + r_ref[...][:, None] * b_ref[...][None, :]
+            o_ref[...] * s_ref[...] + r_ref[...] * b_ref[...]
         )
 
 
@@ -180,7 +206,7 @@ def quant_matmul_packed_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x: (M, K); packed: (ceil(K/per), N) uint8 sub-byte codes.
 
@@ -205,13 +231,13 @@ def quant_matmul_packed_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bkp, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
+            _row_spec(bn),
+            _row_spec(bn),
+            _col_spec(bm),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         interpret=interpret,
-    )(x, packed, scale, bias, rowsum)
+    )(x, packed, _row(scale), _row(bias), _col(rowsum))
 
 
 # ---------------------------------------------------------------------------
@@ -230,11 +256,14 @@ def _int_kernel(x_ref, c_ref, s_ref, b_ref, r_ref, cst_ref, o_ref, acc_ref,
     x = x_ref[...]                                    # (bm, bk) int8 codes
     codes = c_ref[...]                                # (bk, bn) int8 codes
     if k_total % bk:
-        # Ragged K: zero the activation tail; a zeroed int8 operand makes
-        # the out-of-bounds products exact zeros in the int32 accumulator.
-        k0 = pl.program_id(2) * bk
-        kx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + k0
-        x = jnp.where(kx < k_total, x, jnp.zeros_like(x))
+        # Ragged K: zero the weight rows past K; a zeroed int8 operand makes
+        # the out-of-bounds products exact zeros in the int32 accumulator
+        # (integer garbage in the activation tail times 0 is 0). Masking
+        # the (bk, bn) weight tile, not the (bm, bk) activation tile, keeps
+        # the select off int8 tiles shorter than a packed sublane group,
+        # which Mosaic cannot broadcast into at decode M.
+        codes = jnp.where(_k_rows(codes.shape, bk) < k_total, codes,
+                          jnp.zeros_like(codes))
     acc_ref[...] += jax.lax.dot(x, codes, preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -242,9 +271,8 @@ def _int_kernel(x_ref, c_ref, s_ref, b_ref, r_ref, cst_ref, o_ref, acc_ref,
         # One cast of the int32 accumulator, then the zero-point-corrected
         # affine: y = eff_scale*acc + eff_bias*rowsum(qx) + const.
         o_ref[...] = (
-            acc_ref[...].astype(jnp.float32) * s_ref[...][None, :]
-            + r_ref[...][:, None] * b_ref[...][None, :]
-            + cst_ref[...][None, :]
+            acc_ref[...].astype(jnp.float32) * s_ref[...]
+            + r_ref[...] * b_ref[...] + cst_ref[...]
         )
 
 
@@ -259,7 +287,7 @@ def int_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """qx: (M, K) int8 activation codes; codes: (K, N) int8 weight codes.
 
@@ -282,15 +310,16 @@ def int_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            _row_spec(bn),
+            _row_spec(bn),
+            _col_spec(bm),
+            _row_spec(bn),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(qx, codes, eff_scale, eff_bias, rowsum, const)
+    )(qx, codes, _row(eff_scale), _row(eff_bias), _col(rowsum),
+      _row(const))
 
 
 def _int_packed_kernel(x_ref, p_ref, s_ref, b_ref, r_ref, cst_ref, o_ref,
@@ -301,21 +330,21 @@ def _int_packed_kernel(x_ref, p_ref, s_ref, b_ref, r_ref, cst_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]                                    # (bm, bk) int8 codes
-    # Mask activation columns past K: pack-padding words and ragged tails
-    # then multiply a zeroed operand (same scheme as the float kernel).
-    k0 = pl.program_id(2) * bk
-    kx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + k0
-    x = jnp.where(kx < k_total, x, jnp.zeros_like(x))
     p = p_ref[...].astype(jnp.int32)                  # (bk // per, bn)
-    codes = unpack_tile(p, bits).astype(jnp.int8)     # (bk, bn)
+    codes = unpack_tile(p, bits)                      # (bk, bn) int32
+    if k_steps * bk != k_total:
+        # Zero the unpacked weight rows past K (pack-padding codes and
+        # ragged tails), in int32 before the int8 cast — the same scheme
+        # as the unpacked integer kernel.
+        codes = jnp.where(_k_rows(codes.shape, bk) < k_total, codes, 0)
+    codes = codes.astype(jnp.int8)
     acc_ref[...] += jax.lax.dot(x, codes, preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
         o_ref[...] = (
-            acc_ref[...].astype(jnp.float32) * s_ref[...][None, :]
-            + r_ref[...][:, None] * b_ref[...][None, :]
-            + cst_ref[...][None, :]
+            acc_ref[...].astype(jnp.float32) * s_ref[...]
+            + r_ref[...] * b_ref[...] + cst_ref[...]
         )
 
 
@@ -332,7 +361,7 @@ def int_matmul_packed_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Packed twin of ``int_matmul_pallas``: 2/4-bit weight codes are
     decoded to int8 in-register (``layout.unpack_tile``) and fed to the
@@ -354,12 +383,13 @@ def int_matmul_packed_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bkp, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            _row_spec(bn),
+            _row_spec(bn),
+            _col_spec(bm),
+            _row_spec(bn),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(qx, packed, eff_scale, eff_bias, rowsum, const)
+    )(qx, packed, _row(eff_scale), _row(eff_bias), _col(rowsum),
+      _row(const))

@@ -26,7 +26,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -75,11 +74,11 @@ def sharded_embed_lookup(plan: ShardingPlan, table, tokens):
         out = jnp.where(ok[..., None], out, 0)
         return jax.lax.psum(out, m)
 
-    return shard_map(
+    return jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(m, None), bspec),
         out_specs=P(*bspec, None),
-        check_rep=False,
+        check_vma=False,
     )(table, tokens)
 
 
@@ -120,11 +119,11 @@ def vocab_parallel_xent(plan: ShardingPlan | None, logits, targets, vocab: int):
                            tuple(plan.batch_axes))
         return total / cnt
 
-    loss = shard_map(
+    loss = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(*bspec, m), bspec),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(logits, targets)
     return loss
 

@@ -51,6 +51,7 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig
     from repro.core import bop as bop_lib
@@ -63,6 +64,7 @@ def main():
     from repro.launch import steps as steps_lib
     from repro.launch.mesh import batch_axes_of, make_test_mesh
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     shape = ShapeConfig("train", seq_len=args.seq, global_batch=args.batch,
                         kind="train")
@@ -75,12 +77,20 @@ def main():
     recipe = steps_lib.make_recipe(cfg, shape, direction=args.direction,
                                    budget_rbop=args.budget_rbop,
                                    check_every=max(10, args.steps // 10))
-    state = steps_lib.init_train_state(recipe, jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
+
+    def init(k):
+        return steps_lib.init_train_state(recipe, k)
+
     shardings = None
-    if plan is not None:
+    if plan is None:
+        state = init(key)
+    else:
+        # build the state in place on the mesh: materializing it on one
+        # device first would need the whole state to fit there
         shardings = steps_lib.train_state_shardings(
-            recipe, jax.eval_shape(lambda: state), plan)
-        state = jax.tree.map(jax.device_put, state, shardings)
+            recipe, jax.eval_shape(init, key), plan)
+        state = jax.jit(init, out_shardings=shardings)(key)
     step_fn = jax.jit(steps_lib.make_train_step(recipe, plan),
                       donate_argnums=(0,))
 

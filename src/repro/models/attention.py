@@ -363,15 +363,13 @@ def attention_decode_paged(
 
     groups = cfg.n_heads // cfg.n_kv_heads
     qg = q[:, 0].reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
-    impl = qc.matmul_impl
     eff, sinks = _resolve_window(window, kind, cfg)
     out = paged_attention_op(
         qg.astype(COMPUTE_DTYPE), new_pool["k"], new_pool["v"],
         block_table, pos,
         window=eff, sinks=sinks,
         softcap=cfg.attn_softcap,
-        use_pallas=impl != "ref", interpret=impl != "pallas",
-        **scales,
+        impl=qc.matmul_impl, **scales,
     )
     out = out.astype(COMPUTE_DTYPE).reshape(b, 1, cfg.n_heads * cfg.head_dim)
     y = qmatmul(qc, "attn_o", out, p["wo"])

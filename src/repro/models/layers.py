@@ -52,10 +52,7 @@ def qmatmul(qc: QuantContext, name: str, x, w, *, positions: int = 1,
         # int8×int8 in int32 (DESIGN.md §16). Without one, the int-weight ×
         # fp32-act fused-dequant path runs — the asserted oracle.
         y = quant_matmul_qt(
-            x, qw, act_spec=qc.input_spec(name),
-            use_pallas=qc.matmul_impl != "ref",
-            interpret=qc.matmul_impl != "pallas",
-        )
+            x, qw, act_spec=qc.input_spec(name), impl=qc.matmul_impl)
         return y.astype(COMPUTE_DTYPE)
     x = qc.act_in(name, x)
     wq = qc.weight(name, w)

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.quant import blockwise_int8_decode, blockwise_int8_encode
@@ -71,10 +70,10 @@ def make_compressed_pod_psum(mesh, *, pod_axis: str = "pod"):
             return compressed_psum_leaf(gl, rl, pod_axis)
 
         # grads replicated over pod at this point of the pipeline
-        return shard_map(
+        return jax.shard_map(
             _local, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(g, r)
 
     def apply(grads, residuals):

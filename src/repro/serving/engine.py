@@ -337,9 +337,10 @@ class ServingEngine:
 
     ``quant_state=None`` serves fp32; with a quant_state the engine serves
     the packed mixed-precision export (``use_int8=True``, the default) or
-    pure fake-quant. ``matmul_impl`` picks the fused-dequant GEMM backend:
-    "pallas" on TPU, "pallas_interpret" for kernel validation, "ref" (jnp)
-    elsewhere; the default auto-detects.
+    pure fake-quant. ``matmul_impl`` picks the kernel backend of every
+    served path, fp32/bf16 included (fused-dequant GEMMs and paged
+    attention): "pallas" on TPU, "pallas_interpret" for kernel validation
+    in CPU tests, "ref" (jnp) elsewhere; the default auto-detects.
 
     ``kv_layout`` picks the attention cache substrate (DESIGN.md §10):
 
@@ -621,7 +622,7 @@ class ServingEngine:
 
         def _qc(qweights):
             if quant_state is None:
-                return QuantContext(mode="off")
+                return QuantContext(mode="off", matmul_impl=matmul_impl)
             return QuantContext(
                 mode="serve", cfg=quant_state["qcfg"], specs=specs,
                 qweights=qweights, matmul_impl=matmul_impl,

@@ -222,7 +222,7 @@ def test_int_path_equals_fake_quant_oracle(m, k, n, storage, act_bits,
     beta = jnp.maximum(jnp.max(jnp.abs(x)), 1e-3)
     spec = ActQuantSpec(bits=act_bits, beta=beta, signed=act_signed)
 
-    y = quant_matmul_qt(x, qt, act_spec=spec, use_pallas=False)
+    y = quant_matmul_qt(x, qt, act_spec=spec, impl="ref")
     oracle = fake_quant(x, jnp.asarray(float(act_bits)), beta,
                         act_signed) @ qt.dequantize()
     np.testing.assert_allclose(np.asarray(y), np.asarray(oracle),

@@ -32,7 +32,8 @@ def test_fake_quant_kernel_vs_ref(shape, dtype, signed):
     x = jnp.asarray(rng.normal(size=shape), dtype)
     gate = jnp.asarray(rng.uniform(0.2, 5.5, size=(shape[-1],)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0.3, 2.0, size=(shape[-1],)), jnp.float32)
-    got = fake_quant_op(x, gate, beta, signed=signed, use_pallas=True)
+    got = fake_quant_op(x, gate, beta, signed=signed,
+                        impl="pallas_interpret")
     want = fake_quant_ref(
         x.reshape(-1, shape[-1]).astype(jnp.float32), gate, beta, signed
     ).reshape(shape).astype(dtype)
@@ -49,14 +50,15 @@ def test_fake_quant_kernel_matches_core_path():
     x = jnp.asarray(rng.normal(size=(64, 96)).astype(np.float32))
     gate = jnp.asarray(2.5)   # 8-bit
     beta = jnp.asarray(1.2)
-    got = fake_quant_op(x, gate, beta, signed=True)
+    got = fake_quant_op(x, gate, beta, signed=True, impl="pallas_interpret")
     want = gated_fake_quant(x, gate, beta, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
 
 
 def test_fake_quant_per_tensor_scalar_broadcast():
     x = jnp.asarray(np.random.default_rng(1).normal(size=(33, 65)), jnp.float32)
-    got = fake_quant_op(x, jnp.asarray(1.5), jnp.asarray(1.0), signed=True)
+    got = fake_quant_op(x, jnp.asarray(1.5), jnp.asarray(1.0), signed=True,
+                        impl="pallas_interpret")
     want = fake_quant_ref(x.astype(jnp.float32), jnp.full((65,), 1.5),
                           jnp.ones((65,)), True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
@@ -77,7 +79,7 @@ def test_quant_matmul_vs_ref(mkn, bits):
     w = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
     beta = jnp.max(jnp.abs(w), axis=0)
     codes, scale, bias = quantize_to_int(w, bits, beta, True)
-    got = quant_matmul_op(x, codes, scale, bias, use_pallas=True)
+    got = quant_matmul_op(x, codes, scale, bias, impl="pallas_interpret")
     want = quant_matmul_ref(x, codes, scale, bias)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -99,7 +101,7 @@ def test_quant_matmul_bias_path_per_channel_asymmetric(mkn):
     beta = jnp.max(jnp.abs(w), axis=0)
     codes, scale, bias = quantize_to_int(w, 8, beta, signed=False)
     assert float(jnp.abs(bias).max()) > 0.0
-    got = quant_matmul_op(x, codes, scale, bias, use_pallas=True)
+    got = quant_matmul_op(x, codes, scale, bias, impl="pallas_interpret")
     want = quant_matmul_ref(x, codes, scale, bias)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -142,12 +144,28 @@ def test_packed_quant_matmul_vs_int8_oracle(mkn, bits):
     oracle = QuantizedTensor.from_float(w, bits, beta[None, :], True,
                                         storage_bits=bits, pack=False)
     assert qt.packed and qt.codes.shape[0] == -(-k // (8 // bits))
-    want = quant_matmul_qt(x, oracle, use_pallas=False)
-    got_ref = quant_matmul_qt(x, qt, use_pallas=False)
+    want = quant_matmul_qt(x, oracle, impl="ref")
+    got_ref = quant_matmul_qt(x, qt, impl="ref")
     np.testing.assert_array_equal(np.asarray(got_ref), np.asarray(want))
-    got_pl = quant_matmul_qt(x, qt, use_pallas=True, interpret=True)
+    got_pl = quant_matmul_qt(x, qt, impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(got_pl), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_pallas_flags_refuse_interpreter_on_tpu(monkeypatch):
+    """One backend name drives every kernel wrapper; the interpreter is for
+    CPU tests and is refused where the default backend is a TPU."""
+    from repro.kernels import pallas_flags
+
+    assert pallas_flags("ref") == (False, False)
+    assert pallas_flags("pallas") == (True, False)
+    assert pallas_flags("pallas_interpret") == (True, True)
+    with pytest.raises(ValueError, match="impl must be one of"):
+        pallas_flags("interpret")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_flags("pallas") == (True, False)
+    with pytest.raises(ValueError, match="CPU tests"):
+        pallas_flags("pallas_interpret")
 
 
 def test_quant_matmul_end_to_end_error_small():
@@ -157,7 +175,7 @@ def test_quant_matmul_end_to_end_error_small():
     w = jnp.asarray(rng.normal(size=(128, 64)).astype(np.float32))
     beta = jnp.max(jnp.abs(w), axis=0)
     codes, scale, bias = quantize_to_int(w, 8, beta, True)
-    got = quant_matmul_op(x, codes, scale, bias)
+    got = quant_matmul_op(x, codes, scale, bias, impl="pallas_interpret")
     exact = x @ w
     rel = float(jnp.linalg.norm(got - exact) / jnp.linalg.norm(exact))
     assert rel < 1e-2  # int8 absmax grid: ~0.4% RMS weight error
@@ -176,7 +194,8 @@ def test_flash_attention_vs_ref(s, d, window):
     q = jnp.asarray(rng.normal(size=(2, 3, s, d)).astype(np.float32)) * 0.5
     k = jnp.asarray(rng.normal(size=(2, 3, s, d)).astype(np.float32)) * 0.5
     v = jnp.asarray(rng.normal(size=(2, 3, s, d)).astype(np.float32))
-    got = flash_attention_op(q, k, v, causal=True, window=window)
+    got = flash_attention_op(q, k, v, causal=True, window=window,
+                             impl="pallas_interpret")
     want = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
@@ -187,7 +206,8 @@ def test_flash_attention_softcap_and_gqa():
     q = jnp.asarray(rng.normal(size=(1, 4, 128, 32)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(1, 2, 128, 32)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(1, 2, 128, 32)).astype(np.float32))
-    got = flash_attention_op(q, k, v, causal=True, softcap=30.0)
+    got = flash_attention_op(q, k, v, causal=True, softcap=30.0,
+                             impl="pallas_interpret")
     k_r = jnp.repeat(k, 2, axis=1)
     v_r = jnp.repeat(v, 2, axis=1)
     want = attention_ref(q, k_r, v_r, causal=True, softcap=30.0)
@@ -200,7 +220,7 @@ def test_flash_attention_bf16():
     q = jnp.asarray(rng.normal(size=(1, 2, 128, 64)), jnp.bfloat16)
     k = jnp.asarray(rng.normal(size=(1, 2, 128, 64)), jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=(1, 2, 128, 64)), jnp.bfloat16)
-    got = flash_attention_op(q, k, v, causal=True)
+    got = flash_attention_op(q, k, v, causal=True, impl="pallas_interpret")
     want = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                          v.astype(jnp.float32), causal=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),

@@ -45,7 +45,7 @@ def _sample(seed, lead, head_dim, scale_pow):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(head_dim=st.integers(min_value=1, max_value=37),
        group_size=st.integers(min_value=1, max_value=16),
        bits=st.sampled_from([4, 8]),
@@ -73,7 +73,7 @@ def test_roundtrip_error_bounded_per_group(head_dim, group_size, bits,
         float(jnp.max(err_g)), float(jnp.min(scale)))
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(head_dim=st.integers(min_value=1, max_value=37),
        group_size=st.integers(min_value=1, max_value=16),
        bits=st.sampled_from([4, 8]),
@@ -96,7 +96,7 @@ def test_quantize_dequantize_idempotent(head_dim, group_size, bits,
     assert bool(jnp.all(y == y2))
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(head_dim=st.integers(min_value=1, max_value=33),
        seed=st.integers(min_value=0, max_value=2**16))
 def test_int4_pack_roundtrip_matches_int8_codes(head_dim, seed):

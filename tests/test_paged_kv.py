@@ -139,7 +139,7 @@ def test_paged_attention_pallas_matches_ref(window, softcap):
     want = paged_attention_ref(q, kp, vp, table, posj, window=window,
                                softcap=softcap)
     got = paged_attention_op(q, kp, vp, table, posj, window=window,
-                             softcap=softcap, use_pallas=True, interpret=True)
+                             softcap=softcap, impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -370,6 +370,36 @@ def test_engine_ring_and_paged_layouts_agree(arch):
             eng.submit(Request(rid=i, prompt=p, max_new=6))
         outs[layout] = {r.rid: r.output for r in eng.run_to_completion()}
     assert outs["ring"] == outs["paged"]
+
+
+def test_unquantized_engine_serves_through_the_pallas_paged_kernel(
+        monkeypatch):
+    """An fp32/bf16 engine hands its ``matmul_impl`` to the model: with a
+    Pallas backend its decode tick attends through the Pallas paged kernel
+    (on a TPU, ``"pallas"``), not the jnp oracle, and streams the same
+    tokens as a ``ref`` engine."""
+    import repro.kernels.paged_attention.ops as pa_ops
+
+    traced = []
+
+    def counting(*a, **kw):
+        traced.append(kw["interpret"])
+        return real(*a, **kw)
+
+    real = pa_ops.paged_attention_pallas
+    monkeypatch.setattr(pa_ops, "paged_attention_pallas", counting)
+    cfg, params = _model("tinyllama-1.1b")
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, (int(p),)) for p in (5, 9)]
+    outs = {}
+    for impl in ("ref", "pallas_interpret"):
+        traced.clear()
+        eng = ServingEngine(cfg, params, slots=2, max_seq=32,
+                            matmul_impl=impl)
+        outs[impl] = [r.tokens for r in
+                      eng.generate(prompts, SamplingParams(max_new=4))]
+        assert bool(traced) == (impl != "ref"), (impl, traced)
+    assert outs["ref"] == outs["pallas_interpret"]
 
 
 @pytest.mark.parametrize("plen", [11, 16])
@@ -732,7 +762,7 @@ def test_paged_attention_pallas_matches_ref_quantized(bits, window, softcap):
     want = paged_attention_ref(q, kp, vp, table, pos, window=window,
                                softcap=softcap, k_scale=ks, v_scale=vs)
     got = paged_attention_op(q, kp, vp, table, pos, window=window,
-                             softcap=softcap, use_pallas=True, interpret=True,
+                             softcap=softcap, impl="pallas_interpret",
                              k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -867,7 +897,7 @@ def test_windowed_paged_attention_matches_dense_masked_oracle(window, sinks):
                                rtol=1e-5, atol=1e-5)
     got_pl = paged_attention_op(q, kp, vp, jnp.asarray(table), pos,
                                 window=window, sinks=sinks,
-                                use_pallas=True, interpret=True)
+                                impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(got_pl), want,
                                rtol=1e-5, atol=1e-5)
 
@@ -885,13 +915,13 @@ def test_windowed_kernel_bit_identical_on_evicted_tables(window, sinks):
     for r in range(table.shape[0]):
         fl = max((int(pos[r]) - window + 1) // bs, sb)
         ev[r, sb:fl] = -1
-    for use_pallas in (False, True):
+    for impl in ("ref", "pallas_interpret"):
         full = paged_attention_op(q, kp, vp, jnp.asarray(table), pos,
                                   window=window, sinks=sinks,
-                                  use_pallas=use_pallas, interpret=True)
+                                  impl=impl)
         evd = paged_attention_op(q, kp, vp, jnp.asarray(ev), pos,
                                  window=window, sinks=sinks,
-                                 use_pallas=use_pallas, interpret=True)
+                                 impl=impl)
         np.testing.assert_array_equal(np.asarray(full), np.asarray(evd))
 
 
@@ -907,7 +937,7 @@ def test_windowed_pallas_matches_ref_quantized(bits, window, sinks):
     want = paged_attention_ref(q, kp, vp, table, pos, window=window,
                                sinks=sinks, k_scale=ks, v_scale=vs)
     got = paged_attention_op(q, kp, vp, table, pos, window=window,
-                             sinks=sinks, use_pallas=True, interpret=True,
+                             sinks=sinks, impl="pallas_interpret",
                              k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

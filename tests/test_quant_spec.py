@@ -37,7 +37,7 @@ from repro.quant.spec import storage_class_for
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(k=st.integers(min_value=1, max_value=41),
        n=st.integers(min_value=1, max_value=9),
        bits=st.sampled_from([2, 4]),
@@ -63,7 +63,7 @@ def test_pack_unpack_roundtrip(k, n, bits, stacked, unsigned_rng, seed):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(codes))
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(k=st.integers(min_value=1, max_value=33),
        n=st.integers(min_value=1, max_value=8),
        storage=st.sampled_from([2, 4, 8]),
