@@ -1,5 +1,5 @@
 """Benchmark harness entry point — one function per paper table + kernel
-micro-benchmarks + serving throughput + the roofline summary.
+micro-benchmarks + serving throughput.
 
 Prints ``name,us_per_call,derived`` CSV rows (derived = the table's headline
 metric) and writes full tables under artifacts/tables/. With ``--json``,
@@ -690,30 +690,6 @@ def bench_training(tier: str):
     return out
 
 
-# ---------------------------------------------------------------------------
-# Roofline summary (reads dry-run artifacts)
-# ---------------------------------------------------------------------------
-
-
-def bench_roofline():
-    from benchmarks.roofline_report import load_records
-
-    recs = load_records()
-    ok = [r for r in recs if r.get("ok")]
-    if not ok:
-        print("roofline,0,no_dryrun_artifacts")
-        return
-    fracs = [r["roofline"]["roofline_fraction"] for r in ok
-             if r["roofline"].get("roofline_fraction")]
-    doms = {}
-    for r in ok:
-        doms[r["roofline"]["dominant"]] = doms.get(
-            r["roofline"]["dominant"], 0) + 1
-    med = float(np.median(fracs)) if fracs else 0.0
-    print(f"roofline,{len(ok)},cells_ok={len(ok)}/{len(recs)};"
-          f"median_train_roofline_frac={med*100:.1f}%;dominants={doms}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tier", default="smoke",
@@ -738,7 +714,6 @@ def main() -> None:
         bench_table1(args.tier)
         bench_table_bounds(args.tier, "layer", 2)
         bench_table_bounds(args.tier, "indiv", 3)
-    bench_roofline()
 
     if args.json:
         import json

@@ -34,7 +34,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.compile_cache import enable_compile_cache  # noqa: E402
+from repro.compile_cache import (compile_counts,  # noqa: E402
+                                 enable_compile_cache)
 from repro.configs import get_config  # noqa: E402
 from repro.configs.base import ShapeConfig  # noqa: E402
 from repro.core.sites import QuantContext  # noqa: E402
@@ -67,24 +68,10 @@ def check(ok, what) -> None:
         raise CheckFailed(what)
 
 
-class CompileClock:
-    """Seconds JAX spent in backend compilation (persistent-cache reads
-    included, so a warm cache shows up as fewer seconds)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.total = 0.0
-        self._mark = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == self.EVENT:
-            self.total += duration
-
-    def lap(self) -> float:
-        dt, self._mark = self.total - self._mark, self.total
-        return dt
+def compile_seconds() -> float:
+    """Seconds JAX spent in backend compilation so far (persistent-cache
+    reads included, so a warm cache shows up as fewer seconds)."""
+    return sum(seconds for _, seconds in compile_counts().values())
 
 
 def peak_bytes() -> int | None:
@@ -310,7 +297,6 @@ def main() -> int:
               file=sys.stderr)
         return 2
     enable_compile_cache()
-    clock = CompileClock()
     cfg = get_config(ARCH)
     print(f"device {dev.device_kind} x{len(jax.devices())}; {ARCH}: "
           f"{cfg.n_layers} layers, d_model {cfg.d_model}, d_ff {cfg.d_ff}, "
@@ -324,10 +310,11 @@ def main() -> int:
                                             seed=args.seed),
                "train": lambda: train_phase(cfg, seed=args.seed)})
     for name, run in phases.items():
+        compiled = compile_seconds()
         t0 = time.perf_counter()
         result = run()
         result["wall_s"] = time.perf_counter() - t0
-        result["compile_s"] = clock.lap()
+        result["compile_s"] = compile_seconds() - compiled
         result["peak_device_bytes_so_far"] = peak_bytes()
         print(f"phase {name}: {json.dumps(result)}", flush=True)
         del result

@@ -46,6 +46,7 @@ The probe trick: ``a + probe`` with ``probe = 0`` of the gate-group shape makes
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any
 
@@ -181,17 +182,18 @@ class QuantContext:
         for k, v in weight_stats.items():
             self.weight_stats[k] = v
 
+    @contextlib.contextmanager
     def scope(self, name: str):
-        ctx = self
-
-        class _Scope:
-            def __enter__(self_s):
-                ctx._prefix.append(name)
-
-            def __exit__(self_s, *a):
-                ctx._prefix.pop()
-
-        return _Scope()
+        """Sites registered inside are named ``name/...``, and the ops
+        traced inside carry ``name`` in their metadata (``jax.named_scope``,
+        so a profile attributes each site's GEMM and fusions; DESIGN.md
+        §18)."""
+        self._prefix.append(name)
+        try:
+            with jax.named_scope(name):
+                yield
+        finally:
+            self._prefix.pop()
 
     def layer_stack(self, k: int):
         ctx = self
@@ -269,13 +271,15 @@ class QuantContext:
         g = self.gates[key]
         beta = self.ranges[key]["beta"]
         signed = self.ranges[key]["signed"]
-        # Group-reduced |w| for dir_2/dir_3 (paper §2.3).
-        self.weight_stats[key] = self._w_group_stat(w, g)
-        # Probe param: dL/dprobe == (group-summed) dL/dw through the STE.
-        if key in self.probes:
-            w = w + jnp.broadcast_to(
-                self._expand_w_probe(self.probes[key], w), w.shape
-            ).astype(w.dtype)
+        with jax.named_scope("cgmq_stats"):
+            # Group-reduced |w| for dir_2/dir_3 (paper §2.3).
+            self.weight_stats[key] = self._w_group_stat(w, g)
+            # Probe param: dL/dprobe == (group-summed) dL/dw through the
+            # STE.
+            if key in self.probes:
+                w = w + jnp.broadcast_to(
+                    self._expand_w_probe(self.probes[key], w), w.shape
+                ).astype(w.dtype)
         return self._fq(w, g, beta, signed)
 
     def act(self, name: str, a: jnp.ndarray, *, feature_axis: int = -1) -> jnp.ndarray:
@@ -306,12 +310,14 @@ class QuantContext:
         g = self.gates[key]
         beta = self.ranges[key]["beta"]
         signed = self.ranges[key]["signed"]
-        # Activation statistic for dir_2/dir_3 (|mean over batch of a|),
-        # reduced to the gate-group shape.
-        stat = self._act_group_stat(a, g)
-        self.act_stats[key] = {"mean_abs": stat}
-        if key in self.probes:
-            a = a + jnp.broadcast_to(self.probes[key], a.shape).astype(a.dtype)
+        with jax.named_scope("cgmq_stats"):
+            # Activation statistic for dir_2/dir_3 (|mean over batch of a|),
+            # reduced to the gate-group shape.
+            stat = self._act_group_stat(a, g)
+            self.act_stats[key] = {"mean_abs": stat}
+            if key in self.probes:
+                a = a + jnp.broadcast_to(self.probes[key],
+                                         a.shape).astype(a.dtype)
         return self._fq(a, self._expand_act_gate(g, a), self._expand_act_gate(beta, a), signed)
 
     def input_spec(self, name: str):
@@ -363,9 +369,11 @@ class QuantContext:
             return x
         beta = self.ranges[key]["beta"]
         signed = self.ranges[key]["signed"]
-        self.act_stats[key] = {"mean_abs": self._act_group_stat(x, g)}
-        if key in self.probes:
-            x = x + jnp.broadcast_to(self.probes[key], x.shape).astype(x.dtype)
+        with jax.named_scope("cgmq_stats"):
+            self.act_stats[key] = {"mean_abs": self._act_group_stat(x, g)}
+            if key in self.probes:
+                x = x + jnp.broadcast_to(self.probes[key],
+                                         x.shape).astype(x.dtype)
         return self._fq(x, self._expand_act_gate(g, x),
                         self._expand_act_gate(beta, x), signed)
 
@@ -379,9 +387,10 @@ class QuantContext:
 
     # ---- helpers ------------------------------------------------------------
     def _fq(self, x, g, beta, signed):
-        if self.cfg.impl == "residual":
-            return G.residual_fake_quant(x, g, beta, signed)
-        return G.gated_fake_quant(x, g, beta, signed)
+        with jax.named_scope("fake_quant"):
+            if self.cfg.impl == "residual":
+                return G.residual_fake_quant(x, g, beta, signed)
+            return G.gated_fake_quant(x, g, beta, signed)
 
     @staticmethod
     def _expand_act_gate(g: jnp.ndarray, a: jnp.ndarray):

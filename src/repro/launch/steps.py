@@ -344,12 +344,16 @@ def make_train_step(recipe: Recipe, plan: ShardingPlan | None):
             accum, _ = jax.lax.scan(mb_body, acc0, split)
             (loss, (astats, wstats)), grads = accum
         gp, gb, gprobe = grads
-        upd, opt = opt_update((gp, gb), state.opt, (state.params, state.betas))
-        params, betas = apply_updates((state.params, state.betas), upd)
-        cgmq = ctrl.controller_update(
-            state.cgmq, recipe.ccfg, recipe.sites, gprobe, wstats, astats,
-            recipe.budget_bop,
-        )
+        # named scopes carry each phase into the ops' metadata (§18)
+        with jax.named_scope("adam"):
+            upd, opt = opt_update((gp, gb), state.opt,
+                                  (state.params, state.betas))
+            params, betas = apply_updates((state.params, state.betas), upd)
+        with jax.named_scope("cgmq_controller"):
+            cgmq = ctrl.controller_update(
+                state.cgmq, recipe.ccfg, recipe.sites, gprobe, wstats,
+                astats, recipe.budget_bop,
+            )
         metrics = {
             "loss": loss,
             "bop": cgmq.bop,

@@ -57,6 +57,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.sites import QuantContext
 from repro.models import transformer as tfm
+from repro.obs import span
 from repro.core.calibration import calibrate_activations
 from repro.quant import (ActQuantSpec, KVQuantSpec, QuantizedTensor,
                          QuantSpec, export_act_sites, export_sites,
@@ -284,6 +285,12 @@ class Request:
     # TPOT = (finish_s - first_token_s) / (len(output) - 1).
     first_token_s: float | None = None
     finish_s: float | None = None
+    # admit_s: when the request was first bound to a slot (its queue wait
+    # is admit_s - submit_s; a re-admission after preemption keeps it);
+    # prefix_hit_blocks: prompt blocks the prefix cache served it, summed
+    # over its admissions (DESIGN.md §18)
+    admit_s: float | None = None
+    prefix_hit_blocks: int = 0
 
     def __post_init__(self):
         if self.params is None:
@@ -601,6 +608,8 @@ class ServingEngine:
         #                          counters: victim evictions, replays after
         #                          eviction, submit-time rejections, deadline
         #                          expiries, non-finite-logit failures
+        #   admissions / queue_wait_s   first bindings to a slot and their
+        #                          summed admit_s - submit_s (§18)
         self.stats = {"prefill_forwards": 0, "tail_forwards": 0,
                       "teacher_steps": 0, "prefill_chunks": 0,
                       "prompt_tokens": 0, "seed_equiv_forwards": 0,
@@ -609,7 +618,8 @@ class ServingEngine:
                       "shared_admissions": 0, "cow_copies": 0,
                       "preemptions": 0, "resumed_admissions": 0,
                       "rejected_requests": 0, "deadline_expired": 0,
-                      "nan_failures": 0,
+                      "nan_failures": 0, "admissions": 0,
+                      "queue_wait_s": 0.0,
                       "tick_syncs": 0, "admit_syncs": 0, "stat_syncs": 0,
                       "prefill_time_s": 0.0, "decode_time_s": 0.0}
 
@@ -661,26 +671,27 @@ class ServingEngine:
             live = state["active"]
             pre = jnp.zeros_like(live)
             if paged:
-                if wmask is not None:
-                    # §17 out-of-window eviction: release every block wholly
-                    # behind the sliding window (sink blocks pinned) BEFORE
-                    # preemption/allocation, so freed blocks relieve pool
-                    # pressure within the same tick. ``fl`` matches the
-                    # kernel's first-live-block walk exactly, so no evicted
-                    # block is ever read.
-                    fl = jnp.maximum(
-                        (cache["pos"] - win_w + 1) // block_size,
-                        win_sink_blocks)
-                    alloc = kv_pool.evict_out_of_window(
-                        alloc, fl, live, win_sink_blocks)
-                if preemption:
-                    alloc, pre = kv_pool.preempt_for_free(
-                        alloc, cache["pos"], live, state["gen"],
-                        state["stamp"], block_size)
-                    live = live & ~pre
-                alloc = kv_pool.tick_alloc(alloc, cache["pos"], live,
-                                           block_size)
-                table = alloc["table"]
+                with jax.named_scope("kv_alloc"):
+                    if wmask is not None:
+                        # §17 out-of-window eviction: release every block
+                        # wholly behind the sliding window (sink blocks
+                        # pinned) BEFORE preemption/allocation, so freed
+                        # blocks relieve pool pressure within the same tick.
+                        # ``fl`` matches the kernel's first-live-block walk
+                        # exactly, so no evicted block is ever read.
+                        fl = jnp.maximum(
+                            (cache["pos"] - win_w + 1) // block_size,
+                            win_sink_blocks)
+                        alloc = kv_pool.evict_out_of_window(
+                            alloc, fl, live, win_sink_blocks)
+                    if preemption:
+                        alloc, pre = kv_pool.preempt_for_free(
+                            alloc, cache["pos"], live, state["gen"],
+                            state["stamp"], block_size)
+                        live = live & ~pre
+                    alloc = kv_pool.tick_alloc(alloc, cache["pos"], live,
+                                               block_size)
+                    table = alloc["table"]
             logits, cache = tfm.decode_step(
                 _qc(qweights), params, cache, state["last_tok"], cfg,
                 plan=plan, advance=live, block_table=table, window=wmask)
@@ -692,8 +703,9 @@ class ServingEngine:
             # gate idle rows' (stale) temperature to 0 so a retired sampled
             # request can't defeat the all-greedy lax.cond fast path
             temp = jnp.where(emitted, state["temp"], 0.0)
-            nxt = sample_tokens(rows, pair[:, 1], temp, state["top_k"],
-                                state["top_p"])
+            with jax.named_scope("sample"):
+                nxt = sample_tokens(rows, pair[:, 1], temp, state["top_k"],
+                                    state["top_p"])
             nxt = jnp.where(emitted, nxt, state["last_tok"])
             # keys advance only on emission, so a request's position in its
             # key chain equals its emitted-token count — slot placement,
@@ -990,40 +1002,59 @@ class ServingEngine:
         ``block`` drives engine ticks inline until a queue slot frees
         (``evict_lru_prefix`` first drops retained prefix blocks to help
         the pool drain). Returns the request (possibly already done)."""
-        self._validate_request(req)
-        req.prompt = np.asarray(req.prompt, np.int32)
-        ad = self.admission
-        if ad is not None and ad.queue_capacity is not None \
-                and len(self.waiting) >= ad.queue_capacity:
-            if ad.on_full == "evict_lru_prefix":
-                self._drop_retained()
-            if ad.on_full in ("block", "evict_lru_prefix"):
-                for _ in range(ad.block_max_ticks):
-                    if len(self.waiting) < ad.queue_capacity:
-                        break
-                    self.step()
-            if len(self.waiting) >= ad.queue_capacity:
-                return self._reject(req)
-        if req.seq is None:
-            req.seq = next(self._seq_counter)
-        now = self._clock()
-        req.submit_s = now
-        ttft = req.ttft_deadline_s if req.ttft_deadline_s is not None \
-            else (ad.ttft_deadline_s if ad else None)
-        wall = req.deadline_s if req.deadline_s is not None \
-            else (ad.deadline_s if ad else None)
-        req.ttft_by = now + ttft if ttft is not None else math.inf
-        req.deadline_by = now + wall if wall is not None else math.inf
-        self.waiting.push(req)
-        return req
+        with span("engine.submit", rid=req.rid):
+            self._validate_request(req)
+            req.prompt = np.asarray(req.prompt, np.int32)
+            ad = self.admission
+            if ad is not None and ad.queue_capacity is not None \
+                    and len(self.waiting) >= ad.queue_capacity:
+                if ad.on_full == "evict_lru_prefix":
+                    self._drop_retained()
+                if ad.on_full in ("block", "evict_lru_prefix"):
+                    for _ in range(ad.block_max_ticks):
+                        if len(self.waiting) < ad.queue_capacity:
+                            break
+                        self.step()
+                if len(self.waiting) >= ad.queue_capacity:
+                    return self._reject(req)
+            if req.seq is None:
+                req.seq = next(self._seq_counter)
+            now = self._clock()
+            req.submit_s = now
+            ttft = req.ttft_deadline_s if req.ttft_deadline_s is not None \
+                else (ad.ttft_deadline_s if ad else None)
+            wall = req.deadline_s if req.deadline_s is not None \
+                else (ad.deadline_s if ad else None)
+            req.ttft_by = now + ttft if ttft is not None else math.inf
+            req.deadline_by = now + wall if wall is not None else math.inf
+            self.waiting.push(req)
+            return req
 
     def _sync(self, tree, kind: str):
         """Host transfer + ledger entry: every ``device_get`` on the serving
         path goes through here, so ``stats["tick_syncs"]`` /
         ``stats["admit_syncs"]`` are an audited count, and the §8/§12
-        one-sync-per-tick contract is testable."""
+        one-sync-per-tick contract is testable. The ``engine.sync`` span
+        (§18) times the wait under the same ``kind``."""
         self.stats[kind + "_syncs"] += 1
-        return jax.device_get(tree)
+        with span("engine.sync", kind=kind):
+            return jax.device_get(tree)
+
+    def _bind(self, s: int, req: Request):
+        """Bind a request to slot ``s``; the first binding stamps
+        ``admit_s`` and ends its queue wait."""
+        self.slot_req[s] = req
+        if req.admit_s is None:
+            req.admit_s = self._clock()
+            self.stats["admissions"] += 1
+            self.stats["queue_wait_s"] += req.admit_s - req.submit_s
+
+    def _count_prefix(self, req: Request, hits: int, blocks: int):
+        """Count an admission's prefix-cache hits and full prompt blocks in
+        ``stats`` and the request's own ``prefix_hit_blocks``."""
+        self.stats["prefix_hit_blocks"] += hits
+        self.stats["prompt_blocks"] += blocks
+        req.prefix_hit_blocks += hits
 
     def _param_rows(self, req: Request):
         """Lower a request's SamplingParams to the traced operands ``_arm``
@@ -1160,8 +1191,7 @@ class ServingEngine:
         for key in req.prefix_keys:
             self._key_refs[key] = self._key_refs.get(key, 0) + 1
         self._touch_lru(keys)
-        self.stats["prefix_hit_blocks"] += ns
-        self.stats["prompt_blocks"] += fb
+        self._count_prefix(req, ns, fb)
         return row
 
     def _admit_ring(self, s: int, req: Request, prompt: np.ndarray):
@@ -1446,7 +1476,7 @@ class ServingEngine:
                 # NOT jump the queue — that's the no-starvation guarantee
                 break
             self.waiting.pop()
-            self.slot_req[s] = req
+            self._bind(s, req)
             prompt = np.asarray(req.prompt, np.int32)
             rows = self._param_rows(req)
             if req.output:
@@ -1548,8 +1578,7 @@ class ServingEngine:
             for key in kept_keys:
                 self._key_refs[key] = self._key_refs.get(key, 0) + 1
             self._touch_lru(keys)
-            self.stats["prefix_hit_blocks"] += ns
-            self.stats["prompt_blocks"] += fb
+            self._count_prefix(req, ns, fb)
             st.update(pos=plen, row=row, registered=True)
 
     def _finish_prefill(self, s: int, st: dict):
@@ -1577,8 +1606,8 @@ class ServingEngine:
                 for key in keys:
                     self._key_refs[key] = self._key_refs.get(key, 0) + 1
                 self._touch_lru(keys)
-            self.stats["prefix_hit_blocks"] += st["ns"]
-            self.stats["prompt_blocks"] += len(st["toks"]) // self.block_size
+            self._count_prefix(req, st["ns"],
+                               len(st["toks"]) // self.block_size)
         total = len(st["toks"])
         self.stats["prompt_tokens"] += total
         self.stats["seed_equiv_forwards"] += total
@@ -1642,12 +1671,14 @@ class ServingEngine:
                 pad = self._chunk_shape(c)
                 toks = np.zeros((1, pad), np.int32)
                 toks[0, :c] = st["toks"][st["pos"]:st["pos"] + c]
-                self.cache, st["row"] = self._prefill_chunk(
-                    self.params, self.qweights, self.cache,
-                    self.alloc["table"] if self.paged else None,
-                    jnp.asarray(toks), jnp.asarray(c, jnp.int32),
-                    jnp.asarray(s, jnp.int32),
-                    jnp.asarray(st["pos"], jnp.int32))
+                with span("engine.prefill_chunk", rid=st["req"].rid, slot=s,
+                          tokens=c):
+                    self.cache, st["row"] = self._prefill_chunk(
+                        self.params, self.qweights, self.cache,
+                        self.alloc["table"] if self.paged else None,
+                        jnp.asarray(toks), jnp.asarray(c, jnp.int32),
+                        jnp.asarray(s, jnp.int32),
+                        jnp.asarray(st["pos"], jnp.int32))
                 st["pos"] += c
                 self.stats["prefill_chunks"] += 1
                 self.stats["prefill_forwards"] += 1
@@ -1679,7 +1710,7 @@ class ServingEngine:
                 # head-of-line hold, same no-starvation rule as the wave
                 break
             self.waiting.pop()
-            self.slot_req[s] = req
+            self._bind(s, req)
             self._begin_prefill(s, req)
             bound = True
         armed, ran, blocked = self._prefill_tick()
@@ -1712,25 +1743,39 @@ class ServingEngine:
         retire — and, paged, free their KV blocks — inside this same call;
         so do §13 preemptions (victim re-queued, blocks already freed
         in-tick) and non-finite-logit failures (victim retired with
-        ``FINISHED_ERROR``, the rest of the batch unaffected).
+        ``FINISHED_ERROR``, the rest of the batch unaffected). Its phases
+        run under ``engine.*`` spans inside ``engine.step`` (§18).
         """
-        events = self._expire_deadlines()
-        events += self._admit()
-        # nothing ARMED -> no decode tick: PREFILLING slots (continuous
-        # scheduler) hold inactive device rows and only consume admission
-        # work until their final chunk arms them
-        if not any(r is not None and s not in self._pending
-                   for s, r in enumerate(self.slot_req)):
+        with span("engine.step"):
+            with span("engine.expire"):
+                events = self._expire_deadlines()
+            with span("engine.admit"):
+                events += self._admit()
+            # nothing ARMED -> no decode tick: PREFILLING slots (continuous
+            # scheduler) hold inactive device rows and only consume admission
+            # work until their final chunk arms them
+            if not any(r is not None and s not in self._pending
+                       for s, r in enumerate(self.slot_req)):
+                return events
+            t0 = time.perf_counter()
+            with span("engine.tick"):
+                (self.cache, self.state, self.alloc, nxt, emitted, done, pre,
+                 bad) = self._tick(
+                    self.params, self.qweights, self.cache, self.state,
+                    self.alloc)
+            # The one host sync of the tick: five (slots,)-sized vectors.
+            nxt, emitted, done, pre, bad = map(
+                np.asarray, self._sync((nxt, emitted, done, pre, bad), "tick"))
+            self.stats["decode_time_s"] += time.perf_counter() - t0
+            self.stats["decode_ticks"] += 1
+            with span("engine.emit"):
+                self._emit(events, nxt, emitted, done, pre, bad)
             return events
-        t0 = time.perf_counter()
-        (self.cache, self.state, self.alloc, nxt, emitted, done, pre,
-         bad) = self._tick(
-            self.params, self.qweights, self.cache, self.state, self.alloc)
-        # The one host sync of the tick: five (slots,)-sized vectors.
-        nxt, emitted, done, pre, bad = map(
-            np.asarray, self._sync((nxt, emitted, done, pre, bad), "tick"))
-        self.stats["decode_time_s"] += time.perf_counter() - t0
-        self.stats["decode_ticks"] += 1
+
+    def _emit(self, events: list, nxt, emitted, done, pre, bad) -> None:
+        """Host side of a synced tick: requeue preemption victims, retire
+        non-finite rows, append each emitted token and retire finished
+        requests, adding their ``TokenEvent``s to ``events``."""
         for s in np.flatnonzero(pre):
             ev = self._requeue_slot(int(s), blocks_freed=True)
             if ev is not None:
@@ -1757,7 +1802,6 @@ class ServingEngine:
                                      index=len(req.output) - 1,
                                      done=req.done,
                                      finish_reason=req.finish_reason))
-        return events
 
     # ------------------------------------------------------------------
     # Fault-injection seams (serving/faults.py drives these; DESIGN.md §13)
