@@ -1173,26 +1173,35 @@ class ServingEngine:
                     self.alloc["table"], jnp.asarray(int(t), jnp.int32), s)
                 self.stats["teacher_steps"] += 1
             if keys:
-                # register this prompt's full blocks for later sharers; the
-                # table row read is an admission-time sync, not a tick sync
-                trow = np.asarray(self._sync(self.alloc["table"][s],
-                                             "admit"))
-                for j, key in enumerate(keys):
-                    if key not in self._prefix_map:
-                        self._prefix_map[key] = int(trow[j])
-                        if self.lru_capacity > 0:
-                            # LRU retention: the cache itself holds a device
-                            # ref, so the block outlives its live users
-                            self.alloc = self._retain_block(
-                                self.alloc, jnp.asarray(int(trow[j]),
-                                                        jnp.int32))
-                            self._cache_held.add(key)
-                req.prefix_keys = keys
+                self._register_prompt_blocks(s, req, keys)
         for key in req.prefix_keys:
             self._key_refs[key] = self._key_refs.get(key, 0) + 1
         self._touch_lru(keys)
         self._count_prefix(req, ns, fb)
         return row
+
+    def _register_prompt_blocks(self, s: int, req: Request, keys):
+        """Register the slot's full prompt blocks for later sharers (the
+        table row read is an admission-time sync, not a tick sync) and set
+        ``req.prefix_keys`` to the keys whose map entry names this slot's
+        own block. A key another request registered first (under chunked
+        prefill both can miss the cache before either finishes) names that
+        request's block: holding it would keep the entry alive past that
+        block's release, and a later sharer would map a freed block."""
+        trow = np.asarray(self._sync(self.alloc["table"][s], "admit"))
+        held = []
+        for j, key in enumerate(keys):
+            if key not in self._prefix_map:
+                self._prefix_map[key] = int(trow[j])
+                if self.lru_capacity > 0:
+                    # LRU retention: the cache itself holds a device ref,
+                    # so the block outlives its live users
+                    self.alloc = self._retain_block(
+                        self.alloc, jnp.asarray(int(trow[j]), jnp.int32))
+                    self._cache_held.add(key)
+            if self._prefix_map[key] == int(trow[j]):
+                held.append(key)
+        req.prefix_keys = held
 
     def _admit_ring(self, s: int, req: Request, prompt: np.ndarray):
         """Contiguous-layout admission. SSM prompts run the chunk-aligned
@@ -1592,18 +1601,8 @@ class ServingEngine:
         if self.paged and not st["registered"]:
             keys, ns = st["keys"], st["ns"]
             if keys:
-                trow = np.asarray(self._sync(self.alloc["table"][s],
-                                             "admit"))
-                for j, key in enumerate(keys):
-                    if key not in self._prefix_map:
-                        self._prefix_map[key] = int(trow[j])
-                        if self.lru_capacity > 0:
-                            self.alloc = self._retain_block(
-                                self.alloc, jnp.asarray(int(trow[j]),
-                                                        jnp.int32))
-                            self._cache_held.add(key)
-                req.prefix_keys = keys
-                for key in keys:
+                self._register_prompt_blocks(s, req, keys)
+                for key in req.prefix_keys:
                     self._key_refs[key] = self._key_refs.get(key, 0) + 1
                 self._touch_lru(keys)
             self._count_prefix(req, st["ns"],

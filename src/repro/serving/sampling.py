@@ -102,10 +102,22 @@ def mask_logits(logits, top_k, top_p):
     first token is always kept), computed on the post-top-k renormalized
     distribution; ranking ties resolve by stable sort, so the result is
     deterministic and row-independent.
+
+    The kept set is found in rank space and applied in vocabulary order as
+    a per-row cut (DESIGN.md §12): the lowest-ranked kept token's id
+    ``cut`` and its logit ``thresh`` keep every token above ``thresh`` and
+    the ties at ``thresh`` up to id ``cut`` (the stable sort ranks ties by
+    ascending id). One sort carries the ids; no gather, no inverse sort.
     """
+    # One materialized copy feeds the sort, the cut's read-back and the
+    # compare, so a producer fused into each (the temperature divide) can
+    # not round differently per consumer and empty a row.
+    logits = jax.lax.optimization_barrier(logits)
     v = logits.shape[-1]
-    order = jnp.argsort(-logits, axis=-1, stable=True)
-    ranked = jnp.take_along_axis(logits, order, axis=-1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    neg, order = jax.lax.sort((-logits, ids), dimension=1, num_keys=1,
+                              is_stable=True)
+    ranked = -neg
     rank = jnp.arange(v)[None, :]
     k = jnp.where(top_k > 0, top_k, v)[:, None]
     in_k = rank < k
@@ -113,9 +125,14 @@ def mask_logits(logits, top_k, top_p):
     mass_before = jnp.cumsum(probs, axis=-1) - probs
     keep = in_k & (mass_before < top_p[:, None])
     keep = keep | (rank == 0)
-    ranked = jnp.where(keep, ranked, _MASKED)
-    inverse = jnp.argsort(order, axis=-1)
-    return jnp.take_along_axis(ranked, inverse, axis=-1)
+    last = jnp.max(jnp.where(keep, rank, -1), axis=-1, keepdims=True)
+    cut = jnp.max(jnp.where(rank == last, order, -1), axis=-1, keepdims=True)
+    # the cut's logit read back in vocabulary order: token ``cut`` is kept
+    # whatever bits the sort handed back for it
+    thresh = jnp.max(jnp.where(ids == cut, logits, -jnp.inf), axis=-1,
+                     keepdims=True)
+    kept = (logits > thresh) | ((logits == thresh) & (ids <= cut))
+    return jnp.where(kept, logits, _MASKED)
 
 
 def sample_tokens(logits, keys, temperature, top_k, top_p):

@@ -110,6 +110,23 @@ def test_tick_lowering_carries_sample_and_kv_alloc_scopes():
     assert "/kv_alloc/" in text
 
 
+def test_sampled_tick_lowering_has_one_sort_and_no_gather():
+    """``sample_tokens`` at the Qwen3-4B vocabulary and 32 slots lowers to
+    a single sort and no gather: the nucleus cut is applied in vocabulary
+    order, not through a permutation."""
+    from repro.serving.sampling import sample_tokens
+
+    b, v = 32, 151936
+    f32 = jnp.float32
+    text = jax.jit(sample_tokens).lower(
+        jax.ShapeDtypeStruct((b, v), f32),
+        jax.ShapeDtypeStruct((b, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((b,), f32), jax.ShapeDtypeStruct((b,), jnp.int32),
+        jax.ShapeDtypeStruct((b,), f32)).as_text()
+    assert text.count("stablehlo.sort") == 1
+    assert "gather" not in text
+
+
 def test_train_step_lowering_carries_cgmq_scopes():
     cfg = get_smoke_config(ARCH)
     shape = ShapeConfig("t", seq_len=16, global_batch=2, kind="train")
@@ -151,7 +168,13 @@ def test_admission_stamp_and_own_prefix_hits(mode):
     else:  # an undersized pool: requests are preempted and resumed
         eng = _engine(num_blocks=10)
     reqs = _requests(5, max_new=20)
-    for r in reqs:
+    # the first request registers its prompt blocks before the others
+    # bind, so they can share them (two requests prefilling at once both
+    # miss the cache)
+    eng.submit(reqs[0])
+    while not reqs[0].output:
+        eng.step()
+    for r in reqs[1:]:
         eng.submit(r)
     eng.run_to_completion()
     assert all(r.done and r.finish_reason in ("length", "stop")

@@ -494,6 +494,41 @@ def test_cow_sharer_does_not_keep_stale_prefix_entry():
         "late same-prefix admission mapped a freed/recycled block"
 
 
+def test_late_registrant_does_not_hold_another_requests_prefix_entry():
+    """Regression: under chunked prefill two same-prefix requests can both
+    miss the cache (neither has registered yet) and prefill private
+    copies. The one that finishes second must not take references on the
+    first one's map entries: those name blocks its own table does not
+    hold, so they would outlive the first one's retirement (which frees
+    the blocks), and a later same-prefix admission would map freed,
+    possibly recycled, blocks. Its output must match a solo run, and every
+    map entry must name a block the device still counts as referenced."""
+    cfg, params = _model("tinyllama-1.1b")
+    rng = np.random.default_rng(12)
+    head = rng.integers(0, cfg.vocab_size, (16,))
+
+    def prompt(n):
+        return np.concatenate([head, rng.integers(0, cfg.vocab_size, (n,))])
+
+    eng = ServingEngine(cfg, params, slots=3, max_seq=64,
+                        prefill_chunk_tokens=8)
+    eng.submit(Request(rid=0, prompt=prompt(2), max_new=6))   # registers
+    eng.submit(Request(rid=1, prompt=prompt(5), max_new=20))  # registers 2nd
+    while not any(r.rid == 0 for r in eng.finished):
+        eng.step()
+    assert eng.stats["prefix_hit_blocks"] == 0
+    eng.submit(Request(rid=2, prompt=rng.integers(0, cfg.vocab_size, (16,)),
+                       max_new=2))                            # recycles
+    late = prompt(3)
+    eng.submit(Request(rid=3, prompt=late, max_new=4))
+    ref = np.asarray(eng.alloc["ref"])
+    assert all(ref[b] > 0 for b in eng._prefix_map.values())
+    fin = {r.rid: r.output for r in eng.run_to_completion()}
+    assert fin[3] == _solo_output(cfg, params, late, 4,
+                                  prefill_chunk_tokens=8), \
+        "late same-prefix admission mapped a freed/recycled block"
+
+
 def test_retirement_returns_all_blocks_and_evicts_prefix_cache():
     cfg, params = _model("tinyllama-1.1b")
     rng = np.random.default_rng(5)

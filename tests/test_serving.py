@@ -434,6 +434,65 @@ def test_sample_tokens_greedy_rows_bit_exact_and_support():
         assert toks[1] in set(np.argsort(-np.asarray(l[1]))[:3])
 
 
+_F32_MIN = float(np.finfo(np.float32).min)
+
+
+def _mask_logits_by_argsort(logits, top_k, top_p):
+    """The nucleus mask as first written (argsort, gather, cumulative
+    mass, inverse argsort, gather): the oracle ``mask_logits`` must equal
+    bit for bit."""
+    v = logits.shape[-1]
+    order = jnp.argsort(-logits, axis=-1, stable=True)
+    ranked = jnp.take_along_axis(logits, order, axis=-1)
+    rank = jnp.arange(v)[None, :]
+    k = jnp.where(top_k > 0, top_k, v)[:, None]
+    in_k = rank < k
+    probs = jax.nn.softmax(jnp.where(in_k, ranked, _F32_MIN), axis=-1)
+    mass_before = jnp.cumsum(probs, axis=-1) - probs
+    keep = in_k & (mass_before < top_p[:, None])
+    keep = keep | (rank == 0)
+    ranked = jnp.where(keep, ranked, _F32_MIN)
+    inverse = jnp.argsort(order, axis=-1)
+    return jnp.take_along_axis(ranked, inverse, axis=-1)
+
+
+@pytest.mark.parametrize("rows", ["normal", "rounded", "tied"])
+@pytest.mark.parametrize("v", [64, 1000, 151936])
+def test_mask_logits_equals_argsort_oracle_bit_for_bit(v, rows,
+                                                       monkeypatch):
+    """The per-row threshold cut keeps exactly the argsort oracle's lanes,
+    ties included, for every top-k in {0, 1, 3, 8} x top-p in {1e-6, 0.5,
+    0.9, 0.99, 1.0} on flat and peaked rows; so ``sample_tokens`` draws
+    the same tokens under either mask."""
+    from repro.serving import sampling
+
+    grid = [(k, p) for k in (0, 1, 3, 8) for p in (1e-6, 0.5, 0.9, 0.99,
+                                                   1.0)]
+    b = 2 * len(grid)
+    rng = np.random.default_rng(v)
+    scale = np.repeat([1.0, 8.0], len(grid))[:, None]  # flat, peaked
+    l = rng.normal(size=(b, v)) * scale
+    if rows == "rounded":
+        l = np.round(l, 1)
+    elif rows == "tied":
+        l = np.broadcast_to(np.round(l[:, :1], 1), l.shape)
+    l = jnp.asarray(l, jnp.float32)
+    k = jnp.asarray([g[0] for g in grid] * 2, jnp.int32)
+    p = jnp.asarray([g[1] for g in grid] * 2, jnp.float32)
+
+    got = np.asarray(mask_logits(l, k, p))
+    want = np.asarray(_mask_logits_by_argsort(l, k, p))
+    assert np.array_equal(got, want)
+
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(b) + v)
+    temp = jnp.asarray(np.where(np.arange(b) % 7 == 0, 0.0, 0.7),
+                       jnp.float32)
+    toks = np.asarray(sample_tokens(l, keys, temp, k, p))
+    monkeypatch.setattr(sampling, "mask_logits", _mask_logits_by_argsort)
+    np.testing.assert_array_equal(
+        toks, np.asarray(sample_tokens(l, keys, temp, k, p)))
+
+
 @pytest.mark.parametrize("arch", TOKEN_ARCHS)
 def test_generate_argmax_matches_manual_greedy_every_arch(arch):
     """The §12 acceptance gate: temperature=0 generation through the
