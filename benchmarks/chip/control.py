@@ -60,6 +60,9 @@ def train_rows(args, cell) -> None:
                 rec["adam"], rec["recipe"], compute=comp)
             got = train_cell.compare(low, rec["reference"],
                                      lim["leaf_rule"])
+            # the control stands in for the step's arithmetic, not for
+            # where the program placed its state
+            got["state_share_max"] = gaps["state_share_max"]
             row(seed, f"control_{comp}", train_cell.judge(got, lim)[1], got)
         del rec
         gc.collect()

@@ -62,21 +62,24 @@ def serve_window(cell: dict, seed: int, seconds: float, trace_dir: Path):
 def train_window(cell: dict, seed: int, seconds: float, trace_dir: Path):
     """``train_cell.run`` up to the end of its window, traced into
     ``trace_dir``: the train tokens/s and the traced span."""
+    import jax
+
     import train_cell
 
+    devs = jax.devices()[:cell["chips"]]
     harness.enable_cache()
     clock = harness.CompileClock()
     conf = harness.config_file(cell["config"])
     job = harness.traffic_mix(cell["traffic"])
-    cfg, recipe, make, key, state, step = train_cell.build(
-        cell, conf, job, harness.reference(cell["config"]), seed)
-    make_batch = train_cell.batch_maker(cfg, job["batch"], job["seq"])
+    cfg, recipe, make, key, state, step, make_batch = train_cell.build(
+        cell, conf, job, harness.reference(cell["config"]), seed, devs)
     state, batch, _ = train_cell.program_readings(
         state, step, make_batch, key, make, recipe.adam.b1)
     _, win = train_cell.Window(step, make_batch, key, seconds, trace_dir,
                                clock).run(state, batch,
                                           train_cell.CHECK_STEPS)
-    return (win["steps"] * job["batch"] * job["seq"] / win["seconds"],
+    return ((win["steps"] + win["partial"]) * job["batch"] * job["seq"]
+            / win["seconds"],
             {"seconds": win["trace_seconds"], "steps": win["trace_steps"]})
 
 

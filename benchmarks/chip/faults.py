@@ -54,6 +54,24 @@ def cgmq_unchanged():
                     lambda real: lambda state, *a, **k: state)
 
 
+def state_replicated():
+    """The train state is placed whole on every chip of the mesh."""
+    from repro.launch import steps
+
+    return _patched(steps, "train_state_shardings",
+                    lambda real: lambda recipe, sds, plan:
+                    plan.replicated(sds))
+
+
+def exchange_skipped():
+    """The exchange between chips inside the step's ``shard_map`` blocks
+    is left out: each ``psum`` (the vocabulary-parallel embedding's and
+    cross-entropy's) returns the chip's own part."""
+    import jax
+
+    return _patched(jax.lax, "psum", lambda real: lambda x, *a, **k: x)
+
+
 def token_altered():
     """Every sampled or greedy token is shifted by one where the engine
     produces it."""
@@ -88,6 +106,8 @@ def cache_unchanged():
 
 
 TRAIN = {"state_unchanged": state_unchanged, "half_batch": half_batch,
-         "cgmq_unchanged": cgmq_unchanged}
+         "cgmq_unchanged": cgmq_unchanged,
+         "state_replicated": state_replicated,
+         "exchange_skipped": exchange_skipped}
 SERVE = {"token_altered": token_altered, "top_p_ignored": top_p_ignored,
          "cache_unchanged": cache_unchanged}

@@ -1,7 +1,8 @@
 """``train_tok_s``: every token of every train step that completed inside
-the window, over the window's length."""
+the window, and the share of the step in flight at the close that ran
+inside it, over the window's length."""
 
 
 def read(rec):
     w = rec["window"]
-    return w["steps"] * rec["tokens_per_step"] / w["seconds"]
+    return (w["steps"] + w["partial"]) * rec["tokens_per_step"] / w["seconds"]

@@ -13,7 +13,7 @@ import harness  # noqa: E402
 CELL = "qwen3-4b.cgmq.train"
 
 
-def tiny():
+def tiny(cell_name=CELL):
     """The cell at d_model 64, two layers, 2 x 16 tokens. Its limits are
     the tiny model's own, set between what sound runs read there (loss
     under 1.3e-4, gradient under 4.5e-3, change under 2.1e-3 on three
@@ -26,7 +26,7 @@ def tiny():
     conf["config"].update(hidden_size=64, intermediate_size=128,
                           num_attention_heads=4, num_key_value_heads=2,
                           head_dim=16, num_hidden_layers=2, vocab_size=277)
-    cell = copy.deepcopy(harness.cell(CELL))
+    cell = copy.deepcopy(harness.cell(cell_name))
     cell["correct"].update(loss_gap=1e-3, grad_gap=0.03, change_gap=0.01,
                            gate_gap_mean=0.1)
     return dict(cell=cell, conf=conf, mix={"batch": 2, "seq": 16},

@@ -7,9 +7,10 @@ readers need:
 - ``busy_s``: the union of device op intervals, averaged over the chips
   used (the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane);
 - ``ops``: device op events of chip 0 as ``[short name, start_ns,
-  dur_ns]``; ``op_totals``: self seconds per short name (an op that
-  encloses others, such as a ``while``, keeps only its own time), longest
-  first;
+  dur_ns, name]``, the last the event's whole name (on a TPU the HLO
+  instruction, ``%fusion.12 = f32[...] fusion(...), calls=...``);
+  ``op_totals``: self seconds per short name (an op that encloses others,
+  such as a ``while``, keeps only its own time), longest first;
 - ``modules``: the ``XLA Modules`` line of chip 0 (one event per run of a
   compiled program, named after it);
 - ``gaps``: the idle gaps of chip 0 between busy intervals, longest first,
@@ -145,7 +146,7 @@ def reduce(data: dict, n_devices: int, top: int = 10) -> dict:
         by_label[lab] = by_label.get(lab, 0.0) + sec
     return {
         "busy_s": sum(busy) / len(busy),
-        "ops": [[op_name(o), o[1], o[2]] for o in ops0],
+        "ops": [[op_name(o), o[1], o[2], o[0]] for o in ops0],
         "op_totals": sorted(totals.items(), key=lambda kv: -kv[1]),
         "modules": [[m[0], m[1], m[2]] for m in _line(devs[0],
                                                       MODULES_LINE)],

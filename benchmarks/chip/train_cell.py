@@ -9,9 +9,16 @@ loss, the first gradient per leaf as the optimizer got it (its first
 moment after one step, over 1 - beta1), each leaf's change after the two
 (two and not three, so that the reference stays shorter than the
 window), the CGMQ gates after the two controller updates, the learnable
-ranges' change and the controller's BOP. The window then runs the same object for ``--seconds``, one step in
-flight while the next batch is staged; a step counts when it completed
-inside the window.
+ranges' change and the controller's BOP. The window then runs the same
+object for ``--seconds``, one step in flight while the next batch is
+staged; a step counts when it completed inside the window, and the step
+in flight at the close by the share of it that ran inside.
+
+A cell file that states a ``mesh`` (axis name -> size, e.g. ``{"data":
+2, "model": 2}``) runs the program's sharded step over its chips, and
+``state_share_max`` (the fullest chip's share of the train state's
+bytes) is held to its limit; a cell on one chip builds the step with no
+plan, as the program runs on one device.
 
 After the window ``memory_peak_bytes`` is read, the program's state is
 freed, and the plain reference repeats the two steps in float32 from
@@ -31,15 +38,21 @@ import numpy as np
 import harness
 
 CHECK_STEPS = 2
-# the numbers the reference is held to; a null limit in the cell file
-# prints the number without comparing it
+# the numbers the reference is held to, and the state's placement; a
+# null or absent limit in the cell file prints the number without
+# comparing it
 COMPARED = ("loss_gap", "grad_gap", "change_gap", "gate_gap_mean",
-            "gate_gap_worst", "range_change", "bop_gap")
+            "gate_gap_worst", "range_change", "bop_gap", "state_share_max")
 
 
 
-def build(cell: dict, conf: dict, job: dict, ref, seed: int, *, cfg=None):
-    """Recipe, weights maker, state and the jitted step of a train cell."""
+def build(cell: dict, conf: dict, job: dict, ref, seed: int, devs, *,
+          cfg=None):
+    """Recipe, weights maker, state, the jitted step and the batch maker of
+    a train cell. Where the cell states a ``mesh``, the step is the
+    program's sharded step over ``devs`` (``ShardingPlan``): the weights,
+    the state and every batch are made already placed, and no device holds
+    the whole state; otherwise everything lives on one device."""
     import jax
 
     from repro.configs.base import ShapeConfig
@@ -61,10 +74,63 @@ def build(cell: dict, conf: dict, job: dict, ref, seed: int, *, cfg=None):
         st.params = make(k)
         return st
 
-    state = jax.jit(init)(key)
-    step = jax.jit(steps_lib.make_train_step(recipe, None),
-                   donate_argnums=(0,))
-    return cfg, recipe, make, key, state, step
+    if "mesh" not in cell:
+        state = jax.jit(init)(key)
+        step = jax.jit(steps_lib.make_train_step(recipe, None),
+                       donate_argnums=(0,))
+        return (cfg, recipe, make, key, state, step,
+                batch_maker(cfg, job["batch"], job["seq"]))
+    plan = mesh_plan(cell, cfg, devs)
+    sh = steps_lib.train_state_shardings(recipe, jax.eval_shape(init, key),
+                                         plan)
+    make = jax.jit(make, out_shardings=sh.params)
+    state = jax.jit(init, out_shardings=sh)(key)
+    make_batch = batch_maker(cfg, job["batch"], job["seq"], plan)
+    bsh = jax.eval_shape(make_batch, key, 0)
+    step = jax.jit(steps_lib.make_train_step(recipe, plan),
+                   in_shardings=(sh, plan.batch_dict_shardings(bsh)),
+                   out_shardings=(sh, None), donate_argnums=(0,))
+    return cfg, recipe, make, key, state, step, make_batch
+
+
+def mesh_plan(cell: dict, cfg, devs):
+    """The program's ``ShardingPlan`` on the cell's mesh (axis name ->
+    size, in mesh order) over ``devs``; the batch over every axis but
+    ``model``."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.distributed.sharding import ShardingPlan
+    from repro.launch.mesh import batch_axes_of
+
+    axes = tuple(cell["mesh"])
+    sizes = tuple(cell["mesh"][a] for a in axes)
+    if int(np.prod(sizes)) != cell["chips"] or len(devs) != cell["chips"]:
+        raise ValueError(f"mesh {cell['mesh']} over {len(devs)} devices "
+                         f"for a cell on {cell['chips']} chips")
+    mesh = jax.make_mesh(sizes, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs)
+    return ShardingPlan(mesh=mesh, cfg=cfg, batch_axes=batch_axes_of(mesh))
+
+
+def state_share(state, devs) -> float:
+    """The fullest device's bytes of ``state`` over the state's bytes (a
+    state placed whole on every device reads 1)."""
+    import jax
+
+    held = dict.fromkeys(devs, 0)
+    leaves = jax.tree.leaves(state)
+    for a in leaves:
+        for s in a.addressable_shards:
+            held[s.device] += s.data.nbytes
+    return max(held.values()) / sum(a.nbytes for a in leaves)
+
+
+def on_device(f, dev):
+    """``f`` with its output placed whole on ``dev``."""
+    import jax
+
+    return lambda *a: jax.device_put(f(*a), dev)
 
 
 def check_recipe(recipe, r: dict) -> None:
@@ -103,17 +169,20 @@ def host_gates(gates) -> dict:
     return out
 
 
-def batch_maker(cfg, batch: int, seq: int):
-    """``(key, i) -> batch i``: distinct uniform token rows, on device."""
+def batch_maker(cfg, batch: int, seq: int, plan=None):
+    """``(key, i) -> batch i``: distinct uniform token rows, on device
+    (placed by ``plan``'s batch shardings where there is one)."""
     import jax
 
-    @jax.jit
     def make(key, i):
         toks = jax.random.randint(jax.random.fold_in(key, i),
                                   (batch, seq + 1), 0, cfg.vocab_size)
         return {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
 
-    return make
+    if plan is None:
+        return jax.jit(make)
+    out = jax.eval_shape(make, jax.random.PRNGKey(0), 0)
+    return jax.jit(make, out_shardings=plan.batch_dict_shardings(out))
 
 
 def leaf_norms(tree) -> list:
@@ -375,7 +444,7 @@ def compare(prog: dict, ref: dict, rule: float) -> dict:
 def judge(gaps: dict, lim: dict) -> tuple[dict, bool]:
     """Each compared number beside its limit, and whether all hold."""
     checks = {k: {"value": gaps[k], "limit": lim[k]} for k in COMPARED
-              if lim[k] is not None}
+              if lim.get(k) is not None}
     return checks, all(c["value"] <= c["limit"] for c in checks.values())
 
 
@@ -389,7 +458,11 @@ def printable(readings: dict) -> dict:
 
 class Window:
     """Runs the step for ``seconds`` with one step in flight; counts the
-    steps that completed inside the window."""
+    steps that completed inside the window, and the share of the step in
+    flight at the close that ran inside it (from the completions before
+    and after the close). In a traced run the close moves out by the time
+    the profiler's own start and stop take (seconds on four chips, while
+    no step runs), so that its steps per second are an untraced run's."""
 
     def __init__(self, step, make_batch, key, seconds: float,
                  trace_dir: Path | None, clock: harness.CompileClock):
@@ -409,13 +482,16 @@ class Window:
         trace_span = None
         done, i, pending = 0, first, None
         trace_steps = 0
+        paused = 0.0  # the profiler's own start and stop
         synced = [t_open]
         while True:
             if self.trace_dir is not None and trace_span is None \
                     and not tracing and time.monotonic() >= tr[0]:
                 jax.block_until_ready(state)
+                t = time.monotonic()
                 harness.start_trace(self.trace_dir)
                 tracing, t_tr, n_tr = True, time.monotonic(), done
+                paused += t_tr - t
             with harness.span(tracing, "train_step"):
                 state, metrics = self.step(state, batch)
             with harness.span(tracing, "stage_batch"):
@@ -425,7 +501,7 @@ class Window:
                 with harness.span(tracing, "sync"):
                     jax.block_until_ready(pending)
                 synced.append(time.monotonic())
-                if synced[-1] <= t_close:
+                if synced[-1] <= t_close + paused:
                     done += 1
             pending = metrics["loss"]
             if tracing and time.monotonic() >= tr[1]:
@@ -434,12 +510,18 @@ class Window:
                 trace_steps = done - n_tr
                 jax.profiler.stop_trace()
                 tracing = False
-            if time.monotonic() > t_close:
+                paused += time.monotonic() - trace_span[1]
+            if time.monotonic() > t_close + paused:
                 break
         jax.block_until_ready(pending)
-        if time.monotonic() <= t_close:
-            done += 1
-        out = {"steps": done, "seconds": t_close - t_open,
+        synced.append(time.monotonic())
+        # the step in flight at the close started when the one before it
+        # completed; the share of it that ran inside the window
+        t_close += paused
+        k = next(j for j, t in enumerate(synced) if t > t_close)
+        partial = (t_close - synced[k - 1]) / (synced[k] - synced[k - 1])
+        out = {"steps": done, "partial": partial,
+               "seconds": t_close - t_open - paused, "profiler_s": paused,
                "step_s": harness.summary(list(np.diff(synced[1:]))),
                "compiles": self.clock.count - c0,
                "compile_s": self.clock.seconds - cs0,
@@ -460,19 +542,18 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     o = overrides or {}
     cell = o.get("cell") or harness.cell(cell_name)
     devs = (harness.require_chips(cell["chips"]) if o.get("chips", True)
-            else jax.devices()[:1])
+            else jax.devices()[:cell["chips"]])
     if o.get("cache", True):
         harness.enable_cache()
     clock = harness.CompileClock()
     conf = o.get("conf") or harness.config_file(cell["config"])
     ref = harness.reference(cell["config"])
     job = o.get("mix") or harness.traffic_mix(cell["traffic"])
-    cfg, recipe, make, key, state, step = build(cell, conf, job, ref, seed,
-                                                cfg=o.get("cfg"))
+    cfg, recipe, make, key, state, step, make_batch = build(
+        cell, conf, job, ref, seed, devs, cfg=o.get("cfg"))
+    share = state_share(state, devs)
     if o.get("fault"):
         step = o["fault"](step)
-    job = o.get("mix") or harness.traffic_mix(cell["traffic"])
-    make_batch = batch_maker(cfg, job["batch"], job["seq"])
     state, batch, prog = program_readings(state, step, make_batch, key, make,
                                           recipe.adam.b1)
     jax.block_until_ready(state)
@@ -497,11 +578,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(trace_dir, ignore_errors=True)
     del state, batch, step
     gc.collect()
+    # the reference runs on one chip, on the weights and batches the
+    # program got, gathered there
+    make, make_batch = on_device(make, devs[0]), on_device(make_batch,
+                                                          devs[0])
     t_ref = time.monotonic()
     want = reference_readings(ref, conf, make, make_batch, key, recipe.adam,
                               cell["recipe"])
     ref_s = time.monotonic() - t_ref
     gaps = compare(prog, want, cell["correct"]["leaf_rule"])
+    gaps["state_share_max"] = share
     checks, correct = judge(gaps, cell["correct"])
     record.update(
         checks=checks, setup_s=setup_s, memory_peak_bytes=mem, devs=devs,
